@@ -34,7 +34,11 @@ PAPER_FINAL = 426_850
 BENCH_SCALE = int(os.environ.get("REPRO_BENCH_SCALE", "1"))
 BENCH_FILES = int(os.environ.get("REPRO_BENCH_FILES", "8"))
 BENCH_RPF = int(os.environ.get("REPRO_BENCH_RPF", "4000")) * BENCH_SCALE
-CACHE = Path(os.environ.get("REPRO_BENCH_CACHE", "/root/repo/.bench_cache"))
+CACHE = Path(
+    os.environ.get(
+        "REPRO_BENCH_CACHE", Path(__file__).resolve().parents[1] / ".bench_cache"
+    )
+)
 
 
 def bench_spec(key_bits: int = 64) -> CorpusSpec:

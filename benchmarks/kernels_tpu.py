@@ -1,10 +1,10 @@
 """Device-path throughput: hash_mix digesting and sorted_probe membership.
 
-These are the TPU adaptations of the paper's hot loops (DESIGN.md §2),
-measured here on the XLA reference path (CPU container; on TPU the Pallas
-kernels take over).  Derived column reports ids/s so the number is
-directly comparable to the paper's host-side rates (3,243 mol/s naïve
-scan; ~1e6/s dict lookups).
+These are the TPU adaptations of the paper's hot loops, measured on
+whichever path :func:`repro.device.on_tpu` selects: the Pallas kernels on
+a TPU, the XLA reference path elsewhere.  Derived column reports ids/s
+so the number is directly comparable to the paper's host-side rates
+(3,243 mol/s naïve scan; ~1e6/s dict lookups).
 """
 
 from __future__ import annotations

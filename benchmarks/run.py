@@ -41,6 +41,8 @@ import sys
 import time
 from pathlib import Path
 
+from repro.device import use_compile_cache
+
 
 def _write_metrics(
     metrics, env_var: str, default_name: str, tag: str, update: bool
@@ -73,6 +75,7 @@ def main() -> None:
              "without it metrics land in the bench cache (env overrides "
              "such as REPRO_BENCH_EXTRACT_OUT always win)")
     args = ap.parse_args()
+    use_compile_cache()
     if args.scale is not None:
         # must land in the env before the bench modules import common.py
         os.environ["REPRO_BENCH_SCALE"] = str(args.scale)

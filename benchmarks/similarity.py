@@ -88,11 +88,9 @@ def run() -> List[str]:
     qc = popcount_u32(qf).sum(axis=1, dtype=np.int32)
     qn = qf.shape[0]
 
-    import jax
+    from repro.device import on_tpu
 
-    backend = (
-        "pallas-tpu" if jax.default_backend() == "tpu" else "host-blocked"
-    )
+    backend = "pallas-tpu" if on_tpu() else "host-blocked"
 
     # warm every path (allocators, and the jit cache when a TPU is there)
     tanimoto_topk_naive(qf[:2], db, K)

@@ -62,7 +62,6 @@ from .records import find_record_end
 from .verify import (
     VerifyBatcher,
     _recompute,
-    _tpu_backend_active,
     compare_ids_batch,
 )
 

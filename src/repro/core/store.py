@@ -58,6 +58,8 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
+from repro.device import on_tpu
+
 from .bloom import BloomFilter
 from .fingerprint import (
     DEFAULT_FP_BITS,
@@ -642,7 +644,7 @@ class IndexStore:
         both columns and ``False`` in ``hit_mask``.  ``probe`` selects the
         digest-search backend: ``"host"`` (``np.searchsorted``), ``"device"``
         (the ``sorted_probe`` Pallas kernel — jnp reference off-TPU), or
-        ``None``/"auto" (device only when JAX is already running on TPU).
+        ``None``/"auto" (device on a TPU, host elsewhere).
 
         ``digests`` (optional uint64, parallel to ``keys``) skips the
         per-call ``digest_u64`` — the service's router digests a request
@@ -657,7 +659,7 @@ class IndexStore:
         if n == 0:
             return file_ids, offsets, hit
         if probe is None or probe == "auto":
-            probe = "device" if _tpu_backend_active() else "host"
+            probe = "device" if on_tpu() else "host"
         if probe not in ("host", "device"):
             raise ValueError(f"unknown probe backend {probe!r}")
 
@@ -842,7 +844,7 @@ class IndexStore:
     @staticmethod
     def _similar_probe(probe: Optional[str]) -> str:
         if probe is None or probe == "auto":
-            return "device" if _tpu_backend_active() else "host"
+            return "device" if on_tpu() else "host"
         if probe not in ("host", "device"):
             raise ValueError(f"unknown probe backend {probe!r}")
         return probe
@@ -1030,23 +1032,6 @@ class IndexStore:
                 for fp, fc in self._fp_shards.values()
             )
         )
-
-
-def _tpu_backend_active() -> bool:
-    """True only when JAX is ALREADY imported and its backend is TPU.
-
-    Deliberately never imports jax: a host-side lookup must not pay a
-    multi-second framework import just to learn there is no accelerator.
-    """
-    import sys
-
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - defensive
-        return False
 
 
 def _probe_starts_device(

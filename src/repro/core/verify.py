@@ -31,13 +31,15 @@ few µs/record once spans coalesce).  This module batches it:
     - ``vector``  — combined vectorized recompute, string compare;
     - ``process`` — combined recompute chunked over a process pool
       (off-GIL on multi-core hosts; record bytes are pickled to the
-      children, which is the one copy this mode pays);
+      children, which is the one copy this mode pays).  Refused on a
+      TPU: the pool forks, and a forked child of the process that holds
+      the chip must not touch it;
     - ``string``/``digest`` — the per-record reference recompute with a
       string / ``hash_mix``-digest compare (the legacy paths, kept for
       ablations and tests);
     - ``auto``    — ``vector`` recompute, with the compare riding the
-      ``hash_mix`` device batch when JAX is already live on TPU (the
-      store's probe discipline), else the C-speed string compare.
+      ``hash_mix`` device batch on a TPU (:func:`repro.device.on_tpu`),
+      else the C-speed string compare.
 """
 
 from __future__ import annotations
@@ -45,11 +47,12 @@ from __future__ import annotations
 import atexit
 import os
 import struct
-import sys
 import threading
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.device import on_tpu
 
 from .identifiers import canonical_id_from_structure
 from .iobackend import RecordView
@@ -381,18 +384,6 @@ def _vector_ids(metas, atom_parts, bond_parts, ids, fallback) -> None:
 # Digest compare (the hash_mix device batch)
 # ---------------------------------------------------------------------------
 
-def _tpu_backend_active() -> bool:
-    """True only when JAX is ALREADY imported and its backend is TPU
-    (never imports jax — same discipline as the store's probe selection)."""
-    jax = sys.modules.get("jax")
-    if jax is None:
-        return False
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - defensive
-        return False
-
-
 def _bucket(n: int, lo: int = 32) -> int:
     b = lo
     while b < n:
@@ -413,14 +404,12 @@ def compare_ids_batch(
     whose 128-bit digests agree and falling back to a full-string compare
     only on digest disagreement — digest inequality already proves string
     inequality, so the fallback can only confirm the mismatch.
-    ``backend="string"`` compares strings directly.  ``"auto"`` follows the
-    store's probe discipline: the digest path only when JAX is already
-    imported AND running on TPU — a host-side extraction never pays the
-    framework import, and on CPU the C-speed string compare beats the jnp
-    reference kernel anyway.
+    ``backend="string"`` compares strings directly.  ``"auto"`` takes the
+    digest path on a TPU and the string compare elsewhere — on CPU the
+    C-speed string compare beats the jnp reference kernel.
     """
     if backend == "auto":
-        backend = "digest" if _tpu_backend_active() else "string"
+        backend = "digest" if on_tpu() else "string"
     if backend == "string":
         return [e == r for e, r in zip(expected, recomputed)]
     if backend != "digest":
@@ -507,6 +496,11 @@ class VerifyBatcher:
     def __init__(self, backend: str = "auto", combine: bool = True):
         if backend not in ("auto", "string", "digest", "vector", "process"):
             raise ValueError(f"unknown verify backend {backend!r}")
+        if backend == "process" and on_tpu():
+            raise ValueError(
+                "verify backend 'process' forks a pool from the process "
+                "that holds the TPU; use 'auto' or 'vector' on a TPU"
+            )
         self.backend = backend
         self.combine = combine and backend not in ("string", "digest")
         self._lock = threading.Lock()

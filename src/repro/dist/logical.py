@@ -16,8 +16,9 @@ owns the translation:
 * :func:`axis_rules` is a context manager that swaps the active table —
   experiments override individual rules without touching model code.
 * :func:`constrain` applies ``jax.lax.with_sharding_constraint`` with the
-  spec the active rules produce **iff a mesh is active**; with no mesh it
-  is the identity, so single-device smoke tests and the CPU container pay
+  spec the active rules produce **iff a mesh is active** (``jax.set_mesh``
+  over a mesh from :mod:`repro.launch.mesh`); with no mesh it is the
+  identity, so single-device smoke tests and the CPU container pay
   nothing.  Non-divisible dims degrade to replication (never an error).
 * :func:`divisible_spec` is that degradation as a standalone helper — the
   launcher uses it when turning param/cache specs into NamedShardings.
@@ -35,7 +36,7 @@ import threading
 from typing import Any, Mapping, Optional, Sequence, Tuple, Union
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
     "AxisRules",
@@ -51,20 +52,12 @@ __all__ = [
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
 
-def _current_mesh() -> Optional[Mesh]:
-    """The mesh installed by ``with mesh:``, or None outside any context."""
-    try:
-        from jax._src import mesh as mesh_lib
+def _current_mesh() -> Optional[AbstractMesh]:
+    """The mesh installed by ``jax.set_mesh``, or None outside any context.
 
-        m = mesh_lib.thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - jax internals moved
-        try:
-            from jax.interpreters import pxla
-
-            m = pxla.thread_resources.env.physical_mesh
-        except Exception:
-            return None
-    return None if m is None or m.empty else m
+    Works inside ``jit`` too, where the active mesh is abstract."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 @dataclasses.dataclass(frozen=True)
@@ -249,4 +242,7 @@ def constrain(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
     spec = divisible_spec(spec, x.shape, mesh)
     if all(entry is None for entry in tuple(spec)):
         return x
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    if isinstance(x, jax.core.Tracer):
+        # under jit the active mesh is abstract: a bare spec resolves to it
+        return jax.lax.with_sharding_constraint(x, spec)
+    return jax.device_put(x, NamedSharding(jax.sharding.get_mesh(), spec))

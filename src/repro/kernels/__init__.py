@@ -2,9 +2,11 @@
 
 Each kernel ships as ``kernel.py`` (pl.pallas_call + BlockSpec tiling),
 ``ops.py`` (jit'd dispatching wrapper) and ``ref.py`` (pure-jnp oracle).
-On this CPU container kernels execute only under ``interpret=True`` (Mosaic
-lowering is TPU-only); the model code paths default to the reference
-implementations off-TPU.
+On a TPU every entry point runs its Mosaic kernel (``repro.device.on_tpu``
+decides, once, for all of them).  On a CPU the kernels run only under
+``interpret=True`` — in tests — and the entry points take the reference
+implementations; ``tests/test_chip_compile.py`` compiles each kernel for
+a described v5e chip.
 
 * ``hash_mix``        — 128-bit mixing digest of packed identifiers
                         (the InChIKey role for on-device analytics).

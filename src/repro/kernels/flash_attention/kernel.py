@@ -11,6 +11,17 @@ VMEM per step (BQ=BK=512, D=128, bf16 in / f32 scratch):
   q/k/v/out ≈ 4 × 512×128×2 B = 512 KiB, scratch ≈ 512×128×4 + 2×512×4
   ≈ 260 KiB  « 16 MiB ✓
 
+Any lengths: the wrapper pads Skv up to whole key blocks (padded key rows
+are masked, ``k_pos < skv``) and places the queries on a grid of query
+blocks anchored at absolute position 0 — front-padding ``(Skv - Sq) %
+BQ`` rows and back-padding to a whole block, all sliced off afterwards.
+Block sizes depend on Skv only, so a query row lands in the same block
+row, with the same block shapes and the same kv-block schedule, whether
+it comes from a full prefill (Sq == Skv) or from a suffix prefill
+(Sq < Skv).  That keeps the suffix bit-identical to the matching rows of
+the full prefill on any backend, whose matmuls may round differently
+for different tile shapes.
+
 Fully-masked kv blocks (beyond the causal frontier or the sliding window)
 are skipped with ``pl.when`` — with a window the skip fraction approaches
 1 - window/Skv, which is where the kernel's sub-quadratic win comes from.
@@ -33,7 +44,7 @@ NEG_INF = -1e30
 def _fa_kernel(
     q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref,
     *, scale: float, causal: bool, window: Optional[int],
-    bq: int, bk: int, sq: int, skv: int,
+    bq: int, bk: int, q_start: int, skv: int,
 ):
     qi = pl.program_id(1)
     ki = pl.program_id(2)
@@ -45,9 +56,8 @@ def _fa_kernel(
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # absolute positions (queries are the last sq positions of the stream)
-    off = skv - sq
-    q_lo = qi * bq + off          # first query abs position in this block
+    # absolute positions (row 0 of the padded queries sits at q_start)
+    q_lo = qi * bq + q_start      # first query abs position in this block
     q_hi = q_lo + bq - 1
     k_lo = ki * bk
 
@@ -69,7 +79,7 @@ def _fa_kernel(
         )                                           # (BQ, BK)
         q_pos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         k_pos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = jnp.ones((bq, bk), bool)
+        mask = k_pos < skv                          # padded key rows
         if causal:
             mask &= k_pos <= q_pos
         if window is not None:
@@ -111,16 +121,19 @@ def flash_attention_pallas(
         raise ValueError(f"Hq={hq} not a multiple of Hkv={hkv}")
     if scale is None:
         scale = 1.0 / (d ** 0.5)
-    bq = min(block_q, sq)
-    bk = min(block_k, skv)
-    if sq % bq or skv % bk:
-        raise ValueError(f"seq lens ({sq},{skv}) not divisible by blocks ({bq},{bk})")
+    # blocks never exceed the (sublane-rounded) key sequence; queries are
+    # the last sq positions of it, aligned to absolute query blocks
+    bq = min(block_q, _round_up(skv, 8))
+    bk = min(block_k, _round_up(skv, 8))
+    lead = (skv - sq) % bq
+    sq_pad = _round_up(lead + sq, bq)
+    skv_pad = _round_up(skv, bk)
     g = hq // hkv
 
-    qf = q.reshape(b * hq, sq, d)
-    kf = k.reshape(b * hkv, skv, d)
-    vf = v.reshape(b * hkv, skv, d)
-    grid = (b * hq, sq // bq, skv // bk)
+    qf = _pad_seq(q.reshape(b * hq, sq, d), lead, sq_pad)
+    kf = _pad_seq(k.reshape(b * hkv, skv, d), 0, skv_pad)
+    vf = _pad_seq(v.reshape(b * hkv, skv, d), 0, skv_pad)
+    grid = (b * hq, sq_pad // bq, skv_pad // bk)
 
     def kv_index(bh, qi, ki):
         # map flattened q-head index -> flattened kv-head index (GQA)
@@ -128,7 +141,7 @@ def flash_attention_pallas(
 
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, window=window,
-        bq=bq, bk=bk, sq=sq, skv=skv,
+        bq=bq, bk=bk, q_start=skv - sq - lead, skv=skv,
     )
     out = pl.pallas_call(
         kernel,
@@ -139,7 +152,7 @@ def flash_attention_pallas(
             pl.BlockSpec((1, bk, d), kv_index),
         ],
         out_specs=pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, 0)),
-        out_shape=jax.ShapeDtypeStruct((b * hq, sq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b * hq, sq_pad, d), q.dtype),
         scratch_shapes=[
             _vmem((bq, d)),   # acc
             _vmem((bq,)),     # m (running max)
@@ -147,7 +160,20 @@ def flash_attention_pallas(
         ],
         interpret=interpret,
     )(qf, kf, vf)
-    return out.reshape(b, hq, sq, d)
+    return out[:, lead:lead + sq].reshape(b, hq, sq, d)
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _pad_seq(x: jax.Array, front: int, length: int) -> jax.Array:
+    """Zero-pad axis 1 of a (BH, S, D) array: ``front`` rows before, then
+    up to ``length`` rows in all."""
+    back = length - front - x.shape[1]
+    if not front and not back:
+        return x
+    return jnp.pad(x, ((0, 0), (front, back), (0, 0)))
 
 
 def _vmem(shape):

@@ -1,8 +1,8 @@
 """Public jit'd entry points for ``hash_mix``.
 
 ``hash_mix(x)`` dispatches to the Pallas kernel on TPU and to the pure-jnp
-reference elsewhere (CPU containers run the kernel only under
-``interpret=True`` in tests — Mosaic lowering is TPU-only).
+reference elsewhere (on a CPU the kernel runs only under
+``interpret=True``, in tests — Mosaic lowering is TPU-only).
 """
 
 from __future__ import annotations
@@ -13,14 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.device import on_tpu
+
 from .kernel import hash_mix_pallas
 from .ref import hash_mix_ref
 
 __all__ = ["hash_mix", "hash_mix_u64", "digest_ids"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.jit, static_argnames=("seed", "use_pallas", "interpret"))
@@ -32,7 +30,7 @@ def hash_mix(
 ) -> jax.Array:
     """``(N, W) uint32 → (N, 4) uint32`` digest (see kernel/ref)."""
     if use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = on_tpu()
     if use_pallas:
         return hash_mix_pallas(x, seed=seed, interpret=interpret)
     return hash_mix_ref(x, seed=seed)

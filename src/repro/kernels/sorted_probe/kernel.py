@@ -1,22 +1,27 @@
 """Pallas TPU kernel for ``sorted_probe``: fence-partitioned membership.
 
-The TPU adaptation of the paper's index lookup (DESIGN.md §2): a CPU hash
-map is pointer-chasing and does not vectorize; a *sorted dense table* +
+The TPU adaptation of the paper's index lookup: a CPU hash map is
+pointer-chasing and does not vectorize; a *sorted dense table* +
 *fence-partitioned broadcast compare* does:
 
   stage A (jnp, ops.py) — sort queries, assign each to a table block via a
     fence search (fence = every B_T-th table key), bucket queries per block;
   stage B (this kernel)  — grid over table blocks: each step holds one
-    ``(B_T, 2)`` table block and its ``(QMAX, 2)`` query bucket in VMEM and
-    resolves membership with a dense ``(B_T × QMAX)`` lexicographic compare
-    (VPU-regular, branch-free — the TPU-idiomatic substitute for per-query
-    binary search, whose dynamic lane gathers are the expensive thing on
-    this hardware);
+    ``(B_T, 2)`` table block (keys down the sublanes) and its ``(2, QMAX)``
+    query bucket (keys across the lanes) in VMEM and resolves membership
+    with a dense ``(B_T × QMAX)`` lexicographic compare of a column
+    against a row (VPU-regular, branch-free — the TPU-idiomatic
+    substitute for per-query binary search, whose dynamic lane gathers
+    are the expensive thing on this hardware);
   stage C (jnp, ops.py) — scatter results back to original query order.
 
+Every block's last two dims are either the full array dims or (8, 128)
+aligned, which the Mosaic lowering requires: per-block outputs are
+``(nblocks, 1, QMAX)`` rows, not ``(1, QMAX)`` slices of a 2-D array.
+
 VMEM per grid step (B_T=2048, QMAX=512):
-  table 2048×2×4 B = 16 KiB, queries 512×2×4 B = 4 KiB,
-  compare matrices 2×2048×512 bool ≈ 2 MiB  « 16 MiB VMEM ✓
+  table 2048×128(lane-padded)×4 B = 1 MiB, queries 8×512×4 B = 16 KiB,
+  compare matrices 2×2048×512 int32 = 8 MiB  < 16 MiB scoped ✓
 """
 
 from __future__ import annotations
@@ -35,31 +40,30 @@ SENTINEL = 0xFFFFFFFF  # bucket padding key (never a valid query by masking)
 
 def _probe_kernel(t_ref, q_ref, found_ref, pos_ref, *, table_block: int):
     t = t_ref[...]   # (B_T, 2) uint32, sorted ascending
-    q = q_ref[0]     # (QMAX, 2) uint32 bucket (sentinel-padded)
-    t_hi, t_lo = t[:, 0], t[:, 1]
-    q_hi, q_lo = q[:, 0], q[:, 1]
+    q = q_ref[0]     # (2, QMAX) uint32 bucket (sentinel-padded)
+    t_hi, t_lo = t[:, 0:1], t[:, 1:2]   # (B_T, 1) columns
+    q_hi, q_lo = q[0:1, :], q[1:2, :]   # (1, QMAX) rows
     # dense lexicographic compare: (B_T, QMAX)
-    lt = (t_hi[:, None] < q_hi[None, :]) | (
-        (t_hi[:, None] == q_hi[None, :]) & (t_lo[:, None] < q_lo[None, :])
-    )
-    eq = (t_hi[:, None] == q_hi[None, :]) & (t_lo[:, None] == q_lo[None, :])
-    count = jnp.sum(lt.astype(jnp.int32), axis=0)  # lower bound within block
-    found = jnp.any(eq, axis=0)
+    lt = (t_hi < q_hi) | ((t_hi == q_hi) & (t_lo < q_lo))
+    eq = (t_hi == q_hi) & (t_lo == q_lo)
+    # lower bound within block / membership, reduced down the sublanes
+    count = jnp.sum(lt.astype(jnp.int32), axis=0, keepdims=True)
+    found = jnp.max(eq.astype(jnp.int32), axis=0, keepdims=True)
     base = pl.program_id(0) * table_block
-    found_ref[0, :] = found.astype(jnp.int32)
-    pos_ref[0, :] = base + count
+    found_ref[0] = found
+    pos_ref[0] = base + count
 
 
 def probe_blocks_pallas(
     table_padded: jax.Array,   # (nblocks * B_T, 2) uint32, sorted + sentinel pad
-    buckets: jax.Array,        # (nblocks, QMAX, 2) uint32 bucketed queries
+    buckets: jax.Array,        # (nblocks, 2, QMAX) uint32 bucketed queries
     table_block: int = DEFAULT_TABLE_BLOCK,
     interpret: bool = False,
 ):
     """Stage B: per-block membership. Returns (found, pos) of shape
     ``(nblocks, QMAX)``; ``pos`` is the global lower-bound index assuming the
     query was routed to the correct block (stage A's fence invariant)."""
-    nblocks, qmax, _ = buckets.shape
+    nblocks, _, qmax = buckets.shape
     if table_padded.shape[0] != nblocks * table_block:
         raise ValueError(
             f"table rows {table_padded.shape[0]} != nblocks*B_T "
@@ -71,16 +75,16 @@ def probe_blocks_pallas(
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((table_block, 2), lambda i: (i, 0)),
-            pl.BlockSpec((1, qmax, 2), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 2, qmax), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, qmax), lambda i: (i, 0)),
-            pl.BlockSpec((1, qmax), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, qmax), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, qmax), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nblocks, qmax), jnp.int32),
-            jax.ShapeDtypeStruct((nblocks, qmax), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, 1, qmax), jnp.int32),
+            jax.ShapeDtypeStruct((nblocks, 1, qmax), jnp.int32),
         ],
         interpret=interpret,
     )(table_padded, buckets)
-    return found, pos
+    return found[:, 0], pos[:, 0]

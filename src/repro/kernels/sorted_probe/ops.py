@@ -18,6 +18,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.device import on_tpu
+
 from .kernel import DEFAULT_TABLE_BLOCK, SENTINEL, probe_blocks_pallas
 from .ref import pair_eq, pair_less, sort_pairs, sorted_probe_ref
 
@@ -83,6 +85,7 @@ def sorted_probe_pallas(
     rank_c = jnp.minimum(rank, qmax)
     buckets = jnp.full((nblocks, qmax + 1, 2), SENTINEL, jnp.uint32)
     buckets = buckets.at[bid, rank_c].set(sorted_q)[:, :qmax]
+    buckets = jnp.swapaxes(buckets, 1, 2)  # (nblocks, 2, qmax): keys on lanes
 
     # --- stage B: Pallas blocked probe -------------------------------------
     found_b, pos_b = probe_blocks_pallas(
@@ -121,7 +124,7 @@ def sorted_probe(
 ) -> Tuple[jax.Array, jax.Array]:
     """Membership probe; kernel on TPU, pure-jnp reference elsewhere."""
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if use_pallas:
         return sorted_probe_pallas(queries, table, interpret=interpret)
     return sorted_probe_ref(queries, table)

@@ -6,6 +6,8 @@ import functools
 
 import jax
 
+from repro.device import on_tpu
+
 from .kernel import ssd_scan_pallas
 from .ref import ssd_scan_ref
 
@@ -20,7 +22,7 @@ def ssd_scan(
     interpret: bool = False,
 ) -> jax.Array:
     if use_pallas is None:
-        use_pallas = jax.default_backend() == "tpu"
+        use_pallas = on_tpu()
     if use_pallas:
         return ssd_scan_pallas(states, decay, interpret=interpret)
     return ssd_scan_ref(states, decay)

@@ -15,6 +15,8 @@ from typing import Optional, Tuple
 
 import numpy as np
 
+from repro.device import on_tpu
+
 from .kernel import DEFAULT_DB_BLOCK, tanimoto_blocks_pallas
 from .ref import (
     PAD_INDEX,
@@ -207,26 +209,27 @@ def tanimoto_topk_pallas(
         if db_counts is None else np.asarray(db_counts, dtype=np.int32)
     )
 
-    # tile the database into (nblocks, bd) with zero rows (count 0) in the
-    # tail — the kernel masks them via n_db before they can place
-    bd = min(block_d, _ceil_to(n_db, 8))
+    # tile the database into lane-aligned blocks of bd rows, transposed to
+    # (W, rows) so each fingerprint word is a lane-dense row in the kernel;
+    # zero rows (count 0) fill the tail and the kernel masks them via n_db
+    bd = min(block_d, _ceil_to(n_db, 128))
     nblocks = -(-n_db // bd)
     d_pad = nblocks * bd
-    db_p = np.zeros((d_pad, n_words), dtype=np.uint32)
-    db_p[:n_db] = db_fps
-    dc_p = np.zeros(d_pad, dtype=np.int32)
-    dc_p[:n_db] = dc
+    db_t = np.zeros((n_words, d_pad), dtype=np.uint32)
+    db_t[:, :n_db] = db_fps.T
+    dc_p = np.zeros((1, d_pad), dtype=np.int32)
+    dc_p[0, :n_db] = dc
     # queries pad to a sublane multiple; zero-fp rows are sliced back off
     q_pad = _ceil_to(qn, 8)
     q_p = np.zeros((q_pad, n_words), dtype=np.uint32)
     q_p[:qn] = q_fps
-    qc_p = np.zeros((1, q_pad), dtype=np.int32)
-    qc_p[0, :qn] = qc
+    qc_p = np.zeros((q_pad, 1), dtype=np.int32)
+    qc_p[:qn, 0] = qc
     k_pad = _ceil_to(k, 8)
 
     scores, idx = tanimoto_blocks_pallas(
-        db_p,
-        dc_p.reshape(nblocks, bd),
+        db_t,
+        dc_p,
         q_p,
         qc_p,
         block_d=bd,
@@ -259,12 +262,7 @@ def tanimoto_topk(
     CPU-side parity check); ``use_pallas`` overrides auto-detection.
     """
     if use_pallas is None:
-        if interpret:
-            use_pallas = True
-        else:
-            import jax
-
-            use_pallas = jax.default_backend() == "tpu"
+        use_pallas = interpret or on_tpu()
     if use_pallas:
         return tanimoto_topk_pallas(
             q_fps, db_fps, k,

@@ -11,7 +11,7 @@ For each cell: build the production mesh (16×16 single-pod, 2×16×16
 multi-pod) over 512 placeholder host devices, assemble NamedShardings from
 the models' logical param specs, then
 
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered  = jax.jit(step, in_shardings=…, out_shardings=…,
                            donate_argnums=…).lower(*ShapeDtypeStructs)
         compiled = lowered.compile()
@@ -294,7 +294,7 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool, verbose: bool = True
 
     mesh = make_production_mesh(multi_pod=multi_pod)
     t0 = time.perf_counter()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered, pstats = build_lowered(cfg, shape, mesh)
         t_lower = time.perf_counter() - t0
         compiled = lowered.compile()

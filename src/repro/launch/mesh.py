@@ -3,6 +3,11 @@
 A FUNCTION, not a module-level constant — importing this module must never
 touch jax device state (the dry-run sets XLA_FLAGS before first jax init;
 smoke tests and benches must keep seeing 1 device).
+
+Every mesh is built here, with Auto axes: the models place activations
+with ``with_sharding_constraint`` (:func:`repro.dist.logical.constrain`),
+which only accepts Auto axes, and ``jax.make_mesh`` defaults to Explicit
+ones.  Activate a mesh with ``jax.set_mesh(mesh)``.
 """
 
 from __future__ import annotations
@@ -10,6 +15,7 @@ from __future__ import annotations
 from typing import Optional, Tuple
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_mesh", "mesh_from_str", "dp_axes"]
 
@@ -18,14 +24,17 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
-    """Arbitrary mesh (elastic re-carve after node loss, smoke meshes…)."""
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+    """Arbitrary mesh (elastic re-carve after node loss, smoke meshes…)
+    with Auto axes, over ``devices`` (default: all of ``jax.devices()``)."""
     if len(shape) != len(axes):
         raise ValueError(f"shape {shape} / axes {axes} mismatch")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
+    )
 
 
 def mesh_from_str(spec: str):

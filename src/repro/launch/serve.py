@@ -27,6 +27,7 @@ import argparse
 import jax
 
 from repro.configs import ARCH_NAMES, get_config
+from repro.device import use_compile_cache
 from repro.launch.mesh import mesh_from_str
 from repro.models.registry import build_model
 from repro.serve.engine import Engine, ServeConfig
@@ -56,6 +57,7 @@ def main():
         "InChI=1S/C12H22O2/", "InChI=1S/C8H9NO2/",
     ])
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

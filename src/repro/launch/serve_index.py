@@ -51,6 +51,7 @@ import numpy as np
 from repro.core import IndexStore, RecordStore, build_index, extract
 from repro.core.fingerprint import fingerprint_batch
 from repro.core.sdfgen import CorpusSpec, generate_corpus
+from repro.device import use_compile_cache
 from repro.runtime.fault import BackoffPolicy
 from repro.service import (
     FaultInjectingTransport,
@@ -334,6 +335,7 @@ def main():
                          "(default: REPRO_READER_DEPTH env or 32)")
     args = ap.parse_args()
     _maybe_preload_tcmalloc()
+    use_compile_cache()
 
     if args.store:
         store_dir = Path(args.store)

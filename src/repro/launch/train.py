@@ -18,6 +18,7 @@ import jax
 from repro.configs import ARCH_NAMES, get_config
 from repro.core import RecordStore, build_index
 from repro.core.sdfgen import CorpusSpec, generate_corpus
+from repro.device import use_compile_cache
 from repro.data.pipeline import IndexedDataset
 from repro.launch.mesh import mesh_from_str
 from repro.train.optimizer import AdamWConfig
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--corpus-records", type=int, default=4000)
     ap.add_argument("--ckpt-every", type=int, default=10)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if not args.full_config:

@@ -210,18 +210,10 @@ def moe_apply(
         P(mdl, None, fsdp_entry),                 # wd (E→model, F, D→pod+data)
     )
     out_specs = (P(batch_axes, None, None), P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 (check_vma replaced check_rep)
-        smap = partial(
-            jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    else:
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        smap = partial(
-            _shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_rep=False,
-        )
+    smap = partial(
+        jax.shard_map, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
+    )
     y, aux = smap(shard_fn)(
         x, params["router"], params["wg"], params["wu"], params["wd"]
     )

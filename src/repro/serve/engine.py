@@ -117,7 +117,9 @@ class Engine:
 
     def _mesh_ctx(self):
         """The mesh context (activates the sharding rules) or a no-op."""
-        return self.mesh if self.mesh is not None else contextlib.nullcontext()
+        if self.mesh is None:
+            return contextlib.nullcontext()
+        return jax.set_mesh(self.mesh)
 
     def _shard_batch(self, extras: Dict[str, Any]) -> Dict[str, Any]:
         """Spread the request batch over the mesh's data-parallel axes."""

@@ -310,16 +310,16 @@ class ShardRouter:
     # -- the fault-tolerant probe core ---------------------------------------
 
     def _timed_call(self, replica: int, hshard: int, call, timeout_s: float):
-        """One transport probe; its outcome always reaches the tracker —
-        including probes the router already abandoned (late losers)."""
+        """One transport probe; a transport outcome always reaches the
+        tracker — including probes the router already abandoned (late
+        losers).  Any other exception is a bug in the endpoint (a kernel
+        the device refused, a bad argument), not a sick shard: it goes to
+        the caller unscored, so it can never read as degraded results."""
         t0 = time.monotonic()
         try:
             out = call(self._transports[replica], timeout_s)
         except TransportError as e:
             self.health.on_failure(replica, hshard, error_kind(e))
-            raise
-        except Exception as e:  # noqa: BLE001 — endpoint bug, still a failure
-            self.health.on_failure(replica, hshard, "error")
             raise
         self.health.on_success(replica, hshard, time.monotonic() - t0)
         return out

@@ -126,7 +126,11 @@ class Trainer:
         the step function traces with the logical sharding rules active —
         every ``constrain`` in the model resolves against this mesh.
         """
-        with self.mesh if self.mesh is not None else contextlib.nullcontext():
+        ctx = (
+            jax.set_mesh(self.mesh) if self.mesh is not None
+            else contextlib.nullcontext()
+        )
+        with ctx:
             return self._run(until_step, state, on_step, die_at_step)
 
     def _run(self, until_step, state, on_step, die_at_step):

@@ -15,6 +15,7 @@ from repro.dist.compress import (
     quantize_int8,
     topk_mask,
 )
+from repro.launch.mesh import make_mesh
 from repro.dist.logical import (
     DEFAULT_RULES,
     _current_mesh,
@@ -79,9 +80,9 @@ def test_constrain_is_identity_without_mesh():
 
 
 def test_constrain_applies_under_mesh_and_preserves_values():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     x = jnp.arange(8.0).reshape(2, 4)
-    with mesh:
+    with jax.set_mesh(mesh):
         assert _current_mesh() is not None
         y = constrain(x, "batch", "d_ff")
         # jit path (how the models hit it)
@@ -105,8 +106,8 @@ def test_moe_honours_axis_rule_override():
     x = jax.random.normal(
         jax.random.PRNGKey(1), (2, 8, cfg.d_model), compute_dtype(cfg)
     )
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
-    with mesh:
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with jax.set_mesh(mesh):
         y_sharded, aux_sharded = moe.moe_apply(params, cfg, x)
         with axis_rules({"experts": None}):  # expert axis disabled → local
             y_local, aux_local = moe.moe_apply(params, cfg, x)
@@ -257,7 +258,7 @@ def test_engine_with_mesh_matches_unsharded():
     params, specs = api.init(jax.random.PRNGKey(0))
     scfg = ServeConfig(max_new_tokens=6, max_len=64)
     ref = Engine(cfg, params, scfg).generate(["InChI=1S/C4"])
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     got = Engine(
         cfg, params, scfg, mesh=mesh, param_specs=specs
     ).generate(["InChI=1S/C4"])

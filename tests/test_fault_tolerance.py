@@ -542,6 +542,27 @@ def test_router_all_dead_fails_fast_within_backoff(store_dir, probe_keys):
         rt.close()
 
 
+def test_router_reraises_endpoint_bug_unscored(store_dir, probe_keys):
+    """An exception that is not a TransportError (say, a kernel the device
+    refused) reaches the caller on every call; it never marks the shard
+    sick, so it can never turn into degraded results."""
+    rt, inj = _chaos_router(store_dir, fail_threshold=1)
+
+    def refused(*a, **kw):
+        raise ValueError("kernel refused")
+
+    try:
+        for tr in inj:
+            tr.inner.store.lookup_batch = refused
+        for _ in range(3):
+            with pytest.raises(ValueError, match="kernel refused"):
+                rt.lookup_batch_ex(probe_keys[:40])
+        assert not rt.health.has_unhealthy()
+        assert rt.stats.probes_failed == 0
+    finally:
+        rt.close()
+
+
 def test_chaos_acceptance_deterministic_degraded_and_recovery(
     store_dir, probe_keys
 ):

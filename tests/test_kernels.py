@@ -41,10 +41,11 @@ def test_hash_mix_matches_ref(n, w):
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(pal))
 
 
-@pytest.mark.parametrize("block_rows", [8, 64, 1024])
+@pytest.mark.parametrize("block_rows", [8, 64, 1024, 2048, 4096])
 def test_hash_mix_block_size_invariance(block_rows):
+    # 9000 ids span several (8, 128)-tile grid steps at every block size
     rng = np.random.default_rng(7)
-    x = jnp.asarray(rng.integers(0, 2**32, size=(300, 16), dtype=np.uint32))
+    x = jnp.asarray(rng.integers(0, 2**32, size=(9000, 16), dtype=np.uint32))
     ref = hash_mix_ref(x)
     pal = hash_mix_pallas(x, block_rows=block_rows, interpret=True)
     np.testing.assert_array_equal(np.asarray(ref), np.asarray(pal))
@@ -255,6 +256,33 @@ def test_flash_attention_causality_property():
     np.testing.assert_allclose(
         np.asarray(out1)[:, :, :200], np.asarray(out2)[:, :, :200], atol=1e-6
     )
+
+
+@pytest.mark.parametrize("sq,skv", [(1040, 1040), (40, 1040), (200, 200)])
+def test_flash_attention_any_length_matches_ref(sq, skv):
+    """Lengths that are not block multiples pad and mask, not raise."""
+    rng = np.random.default_rng(sq + skv)
+    q = jnp.asarray(rng.standard_normal((1, 4, sq, 32)).astype(np.float32))
+    k = jnp.asarray(rng.standard_normal((1, 2, skv, 32)).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal((1, 2, skv, 32)).astype(np.float32))
+    ref = flash_attention_ref(q, k, v)
+    pal = flash_attention_pallas(q, k, v, interpret=True)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_flash_attention_suffix_rows_bit_identical_to_full():
+    """A suffix query block reproduces the full prefill's rows exactly
+    (the prefix-cache parity promise), whatever the query blocking."""
+    rng = np.random.default_rng(18)
+    skv, suf = 1040, 48
+    q = jnp.asarray(rng.standard_normal((1, 4, skv, 32)).astype(np.float32))
+    k = jnp.asarray(rng.standard_normal((1, 2, skv, 32)).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal((1, 2, skv, 32)).astype(np.float32))
+    full = flash_attention_pallas(q, k, v, interpret=True)
+    tail = flash_attention_pallas(q[:, :, -suf:], k, v, interpret=True)
+    np.testing.assert_array_equal(np.asarray(full)[:, :, -suf:],
+                                  np.asarray(tail))
 
 
 @pytest.mark.parametrize("b,hq,hkv,sq,skv,d,causal,window,chunk", [

@@ -112,6 +112,30 @@ def test_smoke_decode_matches_forward(arch):
     assert err < 0.06, f"{arch}: decode/forward divergence {err}"
 
 
+@pytest.mark.parametrize("dtype,tol", [("float32", 1e-4), ("bfloat16", 0.05)])
+def test_prefill_matches_float32_reference(dtype, tol):
+    """The served prefill of a ragged row agrees with the independent
+    float32 reference (``models/reference.py``): tightly when the model
+    computes in float32, within bf16 rounding when it computes in bf16."""
+    from repro.models.reference import dense_lm_logits
+
+    cfg = dataclasses.replace(get_config("yi-6b").smoke(), n_layers=2,
+                              dtype=dtype)
+    api = build_model(cfg)
+    params, _ = api.init(jax.random.PRNGKey(3))
+    n, width = 37, 48  # a ragged row: 11 pad positions after it
+    toks = jax.random.randint(jax.random.PRNGKey(4), (n,), 0, cfg.vocab_size)
+    batch = {"tokens": jnp.zeros((1, width), jnp.int32).at[0, :n].set(toks),
+             "lengths": jnp.asarray([n], jnp.int32)}
+    got, _ = jax.jit(lambda p, b: api.prefill(p, b, max_len=64))(params, batch)
+    want = jax.jit(lambda p, t: dense_lm_logits(p, cfg, t, jnp.asarray([n - 1])))(
+        params, toks
+    )
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    err = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert err < tol, f"{dtype}: relative L2 {err}"
+
+
 def test_full_configs_match_assignment():
     """The published numbers, verbatim (guards accidental edits)."""
     rows = {

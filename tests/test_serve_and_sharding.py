@@ -121,7 +121,7 @@ SUBPROCESS_PROG = textwrap.dedent(
     loss_ref, _ = jax.jit(api.loss)(params, batch)
 
     mesh = make_mesh((2, 4), ("data", "model"))
-    with mesh:
+    with jax.set_mesh(mesh):
         psh = shardings_from_specs(mesh, specs, params)
         bsh = batch_shardings(mesh, {k: jax.ShapeDtypeStruct(v.shape, v.dtype)
                                      for k, v in batch.items()})
@@ -152,3 +152,48 @@ def test_moe_sharded_equals_single_device():
     out = json.loads(line[len("RESULT:"):])
     assert out["n_dev"] == 8
     assert abs(out["ref"] - out["sharded"]) < 0.03, out
+
+
+FLASH_MESH_PROG = textwrap.dedent(
+    """
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    import json
+    import jax, jax.numpy as jnp, numpy as np
+    from repro.kernels.flash_attention.kernel import flash_attention_pallas
+    from repro.kernels.flash_attention.ops import flash_attention
+    from repro.launch.mesh import make_mesh
+
+    rng = np.random.default_rng(0)
+    mesh = make_mesh((2, 4), ("data", "model"))
+    errs = {}
+    for hkv in (4, 2):  # KV heads that divide the model axis, and not
+        q = jnp.asarray(rng.standard_normal((2, 8, 40, 32)), jnp.float32)
+        k = jnp.asarray(rng.standard_normal((2, hkv, 40, 32)), jnp.float32)
+        v = jnp.asarray(rng.standard_normal((2, hkv, 40, 32)), jnp.float32)
+        want = flash_attention_pallas(q, k, v, interpret=True)
+        fn = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, use_pallas=True, interpret=True))
+        with jax.set_mesh(mesh):
+            got = fn(q, k, v)
+        errs[hkv] = float(jnp.abs(got - want).max())
+    print("RESULT:" + json.dumps(errs))
+    """
+)
+
+
+def test_flash_kernel_under_mesh_matches_single_device():
+    """The Mosaic kernel cannot be auto-partitioned: under a mesh it runs
+    per device under shard_map, heads split over "model", and must give
+    the unsharded result, GQA groups included."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(Path(__file__).resolve().parents[1] / "src")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-c", FLASH_MESH_PROG],
+        capture_output=True, text=True, env=env, timeout=300,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT:")][0]
+    errs = json.loads(line[len("RESULT:"):])
+    assert max(errs.values()) < 1e-5, errs

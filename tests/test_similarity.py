@@ -169,6 +169,36 @@ def test_kernel_interpret_matches_oracle_with_ties():
     assert (s[0, 2:] == PAD_SCORE).all() and (i[0, 2:] == PAD_INDEX).all()
 
 
+@pytest.mark.parametrize("off", [-3, -1, 1, 3])
+def test_kernel_round_quotient_corrects_an_inexact_divide(off):
+    # the chip's divide is a few ulps off; the kernel's scores must still be
+    # the IEEE quotient, so feed it estimates that are off on purpose
+    import jax.numpy as jnp
+
+    from repro.kernels.tanimoto.kernel import MAX_BITS, round_quotient
+
+    rng = np.random.default_rng(15)
+    u = np.concatenate([[1, 2, 3, 7, 1023, 4095, MAX_BITS],
+                        rng.integers(1, MAX_BITS + 1, 4000)]).astype(np.int32)
+    c = np.concatenate([u[:7], rng.integers(1, u[7:] + 1)]).astype(np.int32)
+    c[:3] = 1
+    want = c.astype(np.float32) / u.astype(np.float32)
+    q0 = (want.view(np.int32) + off).view(np.float32)
+    got = np.asarray(round_quotient(
+        jnp.asarray(c, jnp.float32), jnp.asarray(u, jnp.float32), jnp.asarray(q0)
+    ))
+    assert np.array_equal(got.view(np.int32), want.view(np.int32))
+
+
+def test_kernel_refuses_fingerprints_wider_than_exact_scores_allow():
+    from repro.kernels.tanimoto.kernel import MAX_BITS
+
+    w = MAX_BITS // 32 + 1
+    q = np.ones((1, w), np.uint32)
+    with pytest.raises(ValueError, match="bits"):
+        tanimoto_topk(q, q, 1, interpret=True)
+
+
 def test_naive_loop_matches_batched():
     rng = np.random.default_rng(13)
     q, db = _rand_plane(rng, 6, 32), _rand_plane(rng, 90, 32)
