@@ -10,7 +10,7 @@ Phases of the default run, all in this one process:
 * kernels — each of the five Pallas kernels, called through the entry
   point the served paths call, compiles to the Mosaic kernel
   (``tpu_custom_call`` in the compiled program) and matches its reference.
-* index — a generated SDF corpus (``--records``, from ``--seed``) is
+* index — a generated SDF corpus (``RECORDS``, from ``--seed``) is
   indexed, published as a sharded store with fingerprint planes, and
   served through ``QueryService``: ``lookup_batch`` (device digest probe),
   ``similar`` (Tanimoto kernel) and ``fetch`` (``hash_mix`` digest
@@ -72,6 +72,9 @@ ONE_CHIP_LAYERS = 8
 SLOTS, MAX_LEN, BLOCK = 8, 2048, 16
 NEW_TOKENS = 5  # first token from prefill, then four decode steps
 PAPER_RECORDS = 176_929_690
+# the generated corpus: ~415 MB at sdfgen's ~2 KB per record, built in
+# about a minute; the rehearsal's is tiny
+RECORDS, REHEARSE_RECORDS = 200_000, 4_000
 
 
 class Clock:
@@ -256,12 +259,13 @@ def index_phase(rehearse: bool, seed: int, records: int, work: Path) -> None:
             raise AssertionError("lookup: degraded keys or wrong hit count")
 
         fps, _ = fingerprint_batch(present[:64], DEFAULT_FP_BITS)
-        sim = svc.similar(fps, 8)
-        sim_want = ref.similar_batch(fps, 8, probe="host")
-        for g, w in zip(sim[:3], sim_want):
-            np.testing.assert_array_equal(g, w)
-        if sim[3].any():
-            raise AssertionError("similar: degraded queries")
+        for n_q, k in ((64, 8), (8, 200)):  # batched top-8; a k past 128
+            sim = svc.similar(fps[:n_q], k)
+            sim_want = ref.similar_batch(fps[:n_q], k, probe="host")
+            for g, w in zip(sim[:3], sim_want):
+                np.testing.assert_array_equal(g, w)
+            if sim[3].any():
+                raise AssertionError(f"similar: degraded queries at k={k}")
 
         targets = present[:1500] + absent[:40]
         res = svc.fetch(targets)
@@ -276,9 +280,10 @@ def index_phase(rehearse: bool, seed: int, records: int, work: Path) -> None:
             raise AssertionError(f"fetch verified only {verified} records")
         if svc.router.health.has_unhealthy():
             raise AssertionError("a health domain left 'up'")
-    log(f"index: {len(sample)} lookups, 64 similarity queries (top-8) and "
-        f"{len(targets)} fetches match the host path / serial extract byte "
-        f"for byte on the {want_probe} path; no degraded key, all domains up")
+    log(f"index: {len(sample)} lookups, 64 similarity queries (top-8), "
+        f"8 (top-200) and {len(targets)} fetches match the host path / serial "
+        f"extract byte for byte on the {want_probe} path; no degraded key, "
+        f"all domains up")
 
 
 # ---------------------------------------------------------------------------
@@ -430,10 +435,11 @@ def four_chip_phase(rehearse: bool, seed: int) -> None:
     from repro.models.registry import build_model
     from repro.serve.engine import Engine, ServeConfig
 
-    devices = jax.devices()[:4]
-    if len(devices) < 4:
-        raise AssertionError(f"--four-chips needs 4 devices, found {len(devices)}")
-    mesh = make_mesh((1, 4), ("data", "model"), devices=devices)
+    if len(jax.devices()) != 4:
+        raise AssertionError(
+            f"--four-chips needs 4 devices, found {len(jax.devices())}"
+        )
+    mesh = make_mesh((1, 4), ("data", "model"))
     full = get_config("yi-6b")
     if rehearse:
         full = dataclasses.replace(full.smoke(), n_layers=4)
@@ -484,7 +490,7 @@ def four_chip_phase(rehearse: bool, seed: int) -> None:
     blocks = jax.tree_util.tree_map(lambda a: a[: cut.n_layers], params["blocks"])
     params = dict(params, blocks=blocks)
     on_mesh = prefill_logits(cut, params, True)
-    one = jax.device_put(params, devices[0])
+    one = jax.device_put(params, jax.devices()[0])
     del params, blocks
     on_one = prefill_logits(cut, one, False)
     err = rel_err(on_mesh, on_one)
@@ -504,15 +510,11 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--seed", type=int, default=0,
                     help="seed of the corpus, prompts and parameters")
-    ap.add_argument("--records", type=int, default=200_000,
-                    help="records in the generated corpus (index phase)")
     ap.add_argument("--four-chips", action="store_true",
                     help="run only the sharded full-depth LM path on 4 chips")
     ap.add_argument("--rehearse", action="store_true",
                     help="allow the CPU: tiny widths, interpret-mode kernels")
     args = ap.parse_args()
-    if args.rehearse:
-        args.records = min(args.records, 4000)
 
     import jax
 
@@ -535,7 +537,7 @@ def main() -> int:
         else:
             clock.phase("kernels", kernels_phase, args.rehearse)
             clock.phase("index", index_phase, args.rehearse, args.seed,
-                        args.records, work)
+                        REHEARSE_RECORDS if args.rehearse else RECORDS, work)
             clock.phase("lm", lm_phase, args.rehearse, args.seed)
     except Exception:  # noqa: BLE001 — the script's boundary: report, fail
         traceback.print_exc()

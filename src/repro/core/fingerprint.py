@@ -35,6 +35,7 @@ import numpy as np
 __all__ = [
     "DEFAULT_FP_BITS",
     "FP_WORD_BITS",
+    "MAX_FP_BITS",
     "fingerprint_batch",
     "fold_fingerprint",
     "popcount_u32",
@@ -43,6 +44,9 @@ __all__ = [
 
 DEFAULT_FP_BITS = 1024  # 32 uint32 words/row: VMEM-friendly, ~0.5% dense text
 FP_WORD_BITS = 32
+# widest plane a store may publish: the device kernel's scores are exact
+# (correctly rounded) only while every union count stays within 2**12
+MAX_FP_BITS = 4096
 _SHINGLE = 3            # character trigrams: the text-feature shingle width
 
 # splitmix64 finalizer (same public-domain mixer the Bloom sidecars use);

@@ -63,6 +63,7 @@ from repro.device import on_tpu
 from .bloom import BloomFilter
 from .fingerprint import (
     DEFAULT_FP_BITS,
+    MAX_FP_BITS,
     fingerprint_batch,
     popcount_u32,
     words_for,
@@ -238,6 +239,11 @@ def save_sharded(
         raise ValueError(f"n_shards must be a power of two, got {n_shards}")
     if fingerprint_bits is not None:
         words_for(fingerprint_bits)  # validate width up front
+        if fingerprint_bits > MAX_FP_BITS:
+            raise ValueError(
+                f"fingerprint_bits {fingerprint_bits} > {MAX_FP_BITS}: the "
+                f"device similarity kernel scores at most {MAX_FP_BITS} bits"
+            )
     root = Path(root)
     root.mkdir(parents=True, exist_ok=True)
 

@@ -31,9 +31,10 @@ few µs/record once spans coalesce).  This module batches it:
     - ``vector``  — combined vectorized recompute, string compare;
     - ``process`` — combined recompute chunked over a process pool
       (off-GIL on multi-core hosts; record bytes are pickled to the
-      children, which is the one copy this mode pays).  Refused on a
-      TPU: the pool forks, and a forked child of the process that holds
-      the chip must not touch it;
+      children, which is the one copy this mode pays).  The workers
+      start as fresh interpreters, so a process with JAX running is
+      never forked.  Refused on a TPU, where one process holds the chip
+      and nothing else runs beside it;
     - ``string``/``digest`` — the per-record reference recompute with a
       string / ``hash_mix``-digest compare (the legacy paths, kept for
       ablations and tests);
@@ -454,13 +455,12 @@ def _process_pool():
             import multiprocessing
             from concurrent.futures import ProcessPoolExecutor
 
-            try:
-                ctx = multiprocessing.get_context("fork")
-            except ValueError:  # pragma: no cover - non-posix
-                ctx = None
+            # workers start as fresh interpreters, never as forks of this
+            # process: a fork of a process with JAX's threads live can
+            # deadlock
             _PROC_POOL = ProcessPoolExecutor(
                 max_workers=max(1, (os.cpu_count() or 2) - 1),
-                mp_context=ctx,
+                mp_context=multiprocessing.get_context("spawn"),
             )
             atexit.register(_PROC_POOL.shutdown)
     return _PROC_POOL
@@ -498,8 +498,8 @@ class VerifyBatcher:
             raise ValueError(f"unknown verify backend {backend!r}")
         if backend == "process" and on_tpu():
             raise ValueError(
-                "verify backend 'process' forks a pool from the process "
-                "that holds the TPU; use 'auto' or 'vector' on a TPU"
+                "verify backend 'process' starts a process pool beside the "
+                "process that holds the TPU; use 'auto' or 'vector' on a TPU"
             )
         self.backend = backend
         self.combine = combine and backend not in ("string", "digest")

@@ -61,7 +61,7 @@ REMAT_POLICY = os.environ.get("REPRO_REMAT_POLICY", "names")
 #       buffer residency.
 #   REPRO_VERIFY_BACKEND — id-recompute/compare mode for VerifyBatcher:
 #       "auto" (vectorized recompute, digest compare on TPU else string),
-#       "vector", "process" (fork-pool recompute off the GIL), "string" /
+#       "vector", "process" (process-pool recompute off the GIL), "string" /
 #       "digest" (per-record reference modes, combining disabled).
 
 

@@ -2,23 +2,18 @@
 
 TPU → Pallas kernel; elsewhere → pure-jnp reference (XLA fuses it well
 enough for CPU tests, and the dry-run rooflines measure the XLA path).
-
-The compiler cannot partition a Mosaic kernel, so under an active mesh
-the kernel runs inside ``shard_map``: batch over the data axes and heads
-over the axis the ``"heads"`` rule names, each device attending its own
-heads — the layout the models' ``constrain`` calls already give q/k/v.
+The Pallas kernel sees whole arrays: under a mesh the models run it per
+device (:func:`repro.models.common.attend`).
 """
 
 from __future__ import annotations
 
-import functools
 from typing import Optional
 
 import jax
 
 from repro import flags
 from repro.device import on_tpu
-from repro.dist.logical import _current_mesh, current_rules, divisible_spec
 
 from .kernel import flash_attention_pallas
 from .ref import flash_attention_chunked, flash_attention_ref
@@ -41,14 +36,10 @@ def flash_attention(
     if use_pallas is None:
         use_pallas = on_tpu()
     if use_pallas:
-        kernel = functools.partial(
-            flash_attention_pallas, causal=causal, window=window,
-            scale=scale, interpret=interpret,
+        return flash_attention_pallas(
+            q, k, v, causal=causal, window=window, scale=scale,
+            interpret=interpret,
         )
-        mesh = _current_mesh()
-        if mesh is None:
-            return kernel(q, k, v)
-        return _per_device_heads(kernel, mesh, q, k, v)
     if flags.ATTN_IMPL == "chunked":
         return flash_attention_chunked(
             q, k, v, causal=causal, window=window, scale=scale,
@@ -56,26 +47,3 @@ def flash_attention(
         )
     return flash_attention_ref(q, k, v, causal=causal, window=window, scale=scale)
 
-
-def _per_device_heads(kernel, mesh, q, k, v):
-    """Run ``kernel`` under ``shard_map`` with (batch, heads) sharded.
-
-    Query heads split contiguously over the heads axis; when the KV heads
-    do not divide that axis they are repeated up to the query heads first,
-    so every device holds the KV heads its query heads read (GQA group
-    ``h // g``).  Dims the mesh does not divide stay replicated.
-    """
-    rules = current_rules()
-    q_spec = divisible_spec(
-        rules.spec(("batch", "heads", None, None), mesh), q.shape, mesh
-    )
-    kv_spec = divisible_spec(q_spec, k.shape, mesh)
-    if tuple(kv_spec)[:2] != tuple(q_spec)[:2]:
-        g = q.shape[1] // k.shape[1]
-        k = jax.numpy.repeat(k, g, axis=1)
-        v = jax.numpy.repeat(v, g, axis=1)
-        kv_spec = q_spec
-    return jax.shard_map(
-        kernel, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
-        out_specs=q_spec, check_vma=False,
-    )(q, k, v)

@@ -7,10 +7,11 @@ pointer-chasing and does not vectorize; a *sorted dense table* +
   stage A (jnp, ops.py) — sort queries, assign each to a table block via a
     fence search (fence = every B_T-th table key), bucket queries per block;
   stage B (this kernel)  — grid over table blocks: each step holds one
-    ``(B_T, 2)`` table block (keys down the sublanes) and its ``(2, QMAX)``
-    query bucket (keys across the lanes) in VMEM and resolves membership
-    with a dense ``(B_T × QMAX)`` lexicographic compare of a column
-    against a row (VPU-regular, branch-free — the TPU-idiomatic
+    ``(2, B_T)`` table block (hi and lo planes, keys across the lanes) and
+    its ``(2, QMAX)`` query bucket (keys across the lanes) in VMEM, turns
+    the table block into ``(B_T, 2)`` columns in-register, and resolves
+    membership with a dense ``(B_T × QMAX)`` lexicographic compare of a
+    column against a row (VPU-regular, branch-free — the TPU-idiomatic
     substitute for per-query binary search, whose dynamic lane gathers
     are the expensive thing on this hardware);
   stage C (jnp, ops.py) — scatter results back to original query order.
@@ -18,9 +19,12 @@ pointer-chasing and does not vectorize; a *sorted dense table* +
 Every block's last two dims are either the full array dims or (8, 128)
 aligned, which the Mosaic lowering requires: per-block outputs are
 ``(nblocks, 1, QMAX)`` rows, not ``(1, QMAX)`` slices of a 2-D array.
+Neither operand is lane-padded in HBM: a ``(M, 2)`` table laid out as
+tiles would take 64x its size (539 MB of temporaries at M = 2**20,
+compiled for v5e).
 
 VMEM per grid step (B_T=2048, QMAX=512):
-  table 2048×128(lane-padded)×4 B = 1 MiB, queries 8×512×4 B = 16 KiB,
+  table 8(sublane-padded)×2048×4 B = 64 KiB, queries 8×512×4 B = 16 KiB,
   compare matrices 2×2048×512 int32 = 8 MiB  < 16 MiB scoped ✓
 """
 
@@ -39,7 +43,7 @@ SENTINEL = 0xFFFFFFFF  # bucket padding key (never a valid query by masking)
 
 
 def _probe_kernel(t_ref, q_ref, found_ref, pos_ref, *, table_block: int):
-    t = t_ref[...]   # (B_T, 2) uint32, sorted ascending
+    t = t_ref[...].T  # (2, B_T) planes -> (B_T, 2) uint32, sorted ascending
     q = q_ref[0]     # (2, QMAX) uint32 bucket (sentinel-padded)
     t_hi, t_lo = t[:, 0:1], t[:, 1:2]   # (B_T, 1) columns
     q_hi, q_lo = q[0:1, :], q[1:2, :]   # (1, QMAX) rows
@@ -55,7 +59,7 @@ def _probe_kernel(t_ref, q_ref, found_ref, pos_ref, *, table_block: int):
 
 
 def probe_blocks_pallas(
-    table_padded: jax.Array,   # (nblocks * B_T, 2) uint32, sorted + sentinel pad
+    table_planes: jax.Array,   # (2, nblocks * B_T) uint32 hi/lo, sorted + sentinel pad
     buckets: jax.Array,        # (nblocks, 2, QMAX) uint32 bucketed queries
     table_block: int = DEFAULT_TABLE_BLOCK,
     interpret: bool = False,
@@ -64,9 +68,9 @@ def probe_blocks_pallas(
     ``(nblocks, QMAX)``; ``pos`` is the global lower-bound index assuming the
     query was routed to the correct block (stage A's fence invariant)."""
     nblocks, _, qmax = buckets.shape
-    if table_padded.shape[0] != nblocks * table_block:
+    if table_planes.shape[1] != nblocks * table_block:
         raise ValueError(
-            f"table rows {table_padded.shape[0]} != nblocks*B_T "
+            f"table rows {table_planes.shape[1]} != nblocks*B_T "
             f"{nblocks}*{table_block}"
         )
     kernel = functools.partial(_probe_kernel, table_block=table_block)
@@ -74,7 +78,7 @@ def probe_blocks_pallas(
         kernel,
         grid=(nblocks,),
         in_specs=[
-            pl.BlockSpec((table_block, 2), lambda i: (i, 0)),
+            pl.BlockSpec((2, table_block), lambda i: (0, i)),
             pl.BlockSpec((1, 2, qmax), lambda i: (i, 0, 0)),
         ],
         out_specs=[
@@ -86,5 +90,5 @@ def probe_blocks_pallas(
             jax.ShapeDtypeStruct((nblocks, 1, qmax), jnp.int32),
         ],
         interpret=interpret,
-    )(table_padded, buckets)
+    )(table_planes, buckets)
     return found[:, 0], pos[:, 0]

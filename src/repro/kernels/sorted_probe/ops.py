@@ -89,7 +89,7 @@ def sorted_probe_pallas(
 
     # --- stage B: Pallas blocked probe -------------------------------------
     found_b, pos_b = probe_blocks_pallas(
-        t_pad, buckets, table_block=bt, interpret=interpret
+        t_pad.T, buckets, table_block=bt, interpret=interpret
     )
 
     # --- stage C: gather back + overflow fallback --------------------------

@@ -22,8 +22,8 @@ Layout (mirrors ``sorted_probe``'s staged shape):
   (:func:`round_quotient`) since the chip's divide is not;
 
   a running per-query top-k lives in the *output* refs (constant index
-  map → the block stays in VMEM across all grid steps), padded to one
-  full 128-lane tile so the merge concatenates lane-aligned tiles: each
+  map → the block stays in VMEM across all grid steps), padded to whole
+  128-lane tiles so the merge concatenates lane-aligned tiles: each
   step merges its ``(Q, B_D)`` candidate scores into the running heap
   by K rounds of masked max-extraction — first-occurrence ties, which
   (run entries sorted, block indices ascending, run indices always below
@@ -48,6 +48,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.fingerprint import MAX_FP_BITS as MAX_BITS
+
 __all__ = [
     "tanimoto_blocks_pallas",
     "round_quotient",
@@ -57,10 +59,8 @@ __all__ = [
 ]
 
 DEFAULT_DB_BLOCK = 256
-# lane width of the running top-k tiles (k_pad <= HEAP_LANES)
+# the running top-k is padded to a multiple of this many lanes
 HEAP_LANES = 128
-# widest fingerprint whose unions keep round_quotient exact (u <= 2**12)
-MAX_BITS = 4096
 # running-heap slots start at this index with score -1; any real row
 # (score >= 0) displaces them, and survivors are mapped to -1 on the host
 PAD_IDX_SENTINEL = 2**31 - 1
@@ -127,8 +127,8 @@ def _tanimoto_kernel(
     dbc_ref,     # (1, B_D) int32  — its precomputed row popcounts
     q_ref,       # (Q, W) uint32   — the full query plane (every step)
     qc_ref,      # (Q, 1) int32    — query popcounts
-    scores_ref,  # (Q, 128) f32    — running top-k scores (accumulator)
-    idx_ref,     # (Q, 128) int32  — running top-k global row indices
+    scores_ref,  # (Q, H) f32      — running top-k scores (accumulator)
+    idx_ref,     # (Q, H) int32    — running top-k global row indices
     *,
     block_d: int,
     k_pad: int,
@@ -219,8 +219,9 @@ def tanimoto_blocks_pallas(
             f"{n_words * 32}-bit fingerprints: the kernel's exact scores "
             f"take at most {MAX_BITS} bits"
         )
-    if not 1 <= k_pad <= HEAP_LANES:
-        raise ValueError(f"k_pad {k_pad} outside [1, {HEAP_LANES}]")
+    if k_pad < 1:
+        raise ValueError(f"k_pad must be >= 1, got {k_pad}")
+    heap = -(-k_pad // HEAP_LANES) * HEAP_LANES
     qn = queries.shape[0]
     kernel = functools.partial(
         _tanimoto_kernel,
@@ -239,12 +240,12 @@ def tanimoto_blocks_pallas(
             pl.BlockSpec((qn, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((qn, HEAP_LANES), lambda i: (0, 0)),
-            pl.BlockSpec((qn, HEAP_LANES), lambda i: (0, 0)),
+            pl.BlockSpec((qn, heap), lambda i: (0, 0)),
+            pl.BlockSpec((qn, heap), lambda i: (0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((qn, HEAP_LANES), jnp.float32),
-            jax.ShapeDtypeStruct((qn, HEAP_LANES), jnp.int32),
+            jax.ShapeDtypeStruct((qn, heap), jnp.float32),
+            jax.ShapeDtypeStruct((qn, heap), jnp.int32),
         ],
         interpret=interpret,
     )(db_t, dbc, queries, q_counts)

@@ -27,14 +27,12 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...], devices=None):
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
     """Arbitrary mesh (elastic re-carve after node loss, smoke meshes…)
-    with Auto axes, over ``devices`` (default: all of ``jax.devices()``)."""
+    with Auto axes."""
     if len(shape) != len(axes):
         raise ValueError(f"shape {shape} / axes {axes} mismatch")
-    return jax.make_mesh(
-        shape, axes, axis_types=(AxisType.Auto,) * len(axes), devices=devices
-    )
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_from_str(spec: str):

@@ -18,6 +18,7 @@ Conventions
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -26,7 +27,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.configs.base import ModelConfig
-from repro.dist.logical import constrain
+from repro.device import on_tpu
+from repro.dist.logical import constrain, current_rules, divisible_spec
 from repro.kernels.flash_attention.ops import flash_attention
 
 __all__ = [
@@ -36,6 +38,7 @@ __all__ = [
     "rmsnorm",
     "rope_freqs",
     "apply_rope",
+    "attend",
     "attention_init",
     "attention_apply",
     "attention_decode",
@@ -107,6 +110,52 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
 # attention
 # ---------------------------------------------------------------------------
 
+def attend(
+    q: jax.Array,                      # (B, H, Sq, Dh)
+    k: jax.Array,                      # (B, Hkv, Skv, Dh)
+    v: jax.Array,
+    causal: bool = True,
+    window: Optional[int] = None,
+    use_pallas: Optional[bool] = None,
+    interpret: bool = False,
+) -> jax.Array:
+    """:func:`flash_attention`, laid out on the active mesh.
+
+    The compiler cannot partition a Mosaic kernel, so under a mesh the
+    Pallas kernel runs inside ``shard_map``: batch over the data axes and
+    heads over the axis the ``"heads"`` rule names, each device attending
+    its own heads — the layout ``constrain`` already gives q/k/v.  Query
+    heads split contiguously; when the KV heads do not divide that axis
+    they are repeated up to the query heads first, so every device holds
+    the KV heads its query heads read (GQA group ``h // g``).  Dims the
+    mesh does not divide stay replicated.  The XLA path is partitioned
+    by the compiler and runs as is.
+    """
+    if use_pallas is None:
+        use_pallas = on_tpu()
+    fn = functools.partial(
+        flash_attention, causal=causal, window=window,
+        use_pallas=use_pallas, interpret=interpret,
+    )
+    mesh = jax.sharding.get_abstract_mesh()
+    if not use_pallas or mesh.empty:
+        return fn(q, k, v)
+    q_spec = divisible_spec(
+        current_rules().spec(("batch", "heads", None, None), mesh),
+        q.shape, mesh,
+    )
+    kv_spec = divisible_spec(q_spec, k.shape, mesh)
+    if tuple(kv_spec)[:2] != tuple(q_spec)[:2]:
+        g = q.shape[1] // k.shape[1]
+        k = jnp.repeat(k, g, axis=1)
+        v = jnp.repeat(v, g, axis=1)
+        kv_spec = q_spec
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+        out_specs=q_spec, check_vma=False,
+    )(q, k, v)
+
+
 def attention_init(key, cfg: ModelConfig) -> Tuple[PyTree, PyTree]:
     d, h, hkv = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     dh = cfg.resolved_head_dim
@@ -176,7 +225,7 @@ def attention_apply(
     q = constrain(q, "batch", "seq", "heads", None)
     k = constrain(k, "batch", "seq", "heads", None)
     v = constrain(v, "batch", "seq", "heads", None)
-    out = flash_attention(
+    out = attend(
         jnp.swapaxes(q, 1, 2),
         jnp.swapaxes(k, 1, 2),
         jnp.swapaxes(v, 1, 2),

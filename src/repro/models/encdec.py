@@ -26,6 +26,7 @@ from repro.dist.logical import constrain
 from repro.models.common import (
     _qkv,
     apply_rope,
+    attend,
     attention_apply,
     attention_decode,
     attention_init,
@@ -183,7 +184,6 @@ def encdec_prefill(params, cfg: ModelConfig, frames, tokens, max_len=None,
     positions = jnp.arange(s)[None, :]
 
     def body(x, blk):
-        from repro.kernels.flash_attention.ops import flash_attention
 
         h = rmsnorm(x, blk["ln1"], cfg.norm_eps)
         q, k, v = _qkv(blk["self"], cfg, h)
@@ -194,7 +194,7 @@ def encdec_prefill(params, cfg: ModelConfig, frames, tokens, max_len=None,
             "k": jnp.pad(kc, ((0, 0), (0, 0), (0, max_len - s), (0, 0))).astype(cdt),
             "v": jnp.pad(vc, ((0, 0), (0, 0), (0, max_len - s), (0, 0))).astype(cdt),
         }
-        att = flash_attention(jnp.swapaxes(q, 1, 2), kc, vc, causal=True)
+        att = attend(jnp.swapaxes(q, 1, 2), kc, vc, causal=True)
         att = jnp.swapaxes(att, 1, 2).reshape(b, s, -1)
         x = x + att @ blk["self"]["wo"].astype(cdt)
 
@@ -217,7 +217,7 @@ def encdec_prefill(params, cfg: ModelConfig, frames, tokens, max_len=None,
         if cfg.qkv_bias:
             qx = qx + blk["cross"]["bq"].astype(cdt)
         qx = qx.reshape(b, s, hq, dh_)
-        att_x = flash_attention(
+        att_x = attend(
             jnp.swapaxes(qx, 1, 2), cross_kv["k"], cross_kv["v"],
             causal=False,
         )

@@ -22,6 +22,7 @@ from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.models import moe as moe_mod
 from repro.models.common import (
+    attend,
     attention_decode,
     attention_init,
     chunked_xent,
@@ -212,7 +213,6 @@ def hybrid_prefill(params, cfg: ModelConfig, tokens, max_len: Optional[int] = No
 
     def body(x, blk):
         from repro.models.common import _qkv, apply_rope
-        from repro.kernels.flash_attention.ops import flash_attention
 
         aux = jnp.zeros((), jnp.float32)
         mi = fi_moe = fi_mlp = 0
@@ -229,7 +229,7 @@ def hybrid_prefill(params, cfg: ModelConfig, tokens, max_len: Optional[int] = No
                     "k": jnp.pad(kc, ((0, 0), (0, 0), (0, max_len - s), (0, 0))).astype(cdt),
                     "v": jnp.pad(vc, ((0, 0), (0, 0), (0, max_len - s), (0, 0))).astype(cdt),
                 }
-                att = flash_attention(jnp.swapaxes(q, 1, 2), kc, vc, causal=True)
+                att = attend(jnp.swapaxes(q, 1, 2), kc, vc, causal=True)
                 att = jnp.swapaxes(att, 1, 2).reshape(b, s, -1)
                 x = x + constrain(
                     att @ blk["attn"]["wo"].astype(cdt), *flags.residual_axes()

@@ -26,6 +26,7 @@ from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.models import moe as moe_mod
 from repro.models.common import (
+    attend,
     attention_apply,
     attention_decode,
     attention_decode_paged,
@@ -266,7 +267,6 @@ def lm_prefill(
     per = len(windows)
 
     def sub_with_cache(p, x, window):
-        from repro.kernels.flash_attention.ops import flash_attention
 
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         q, k, v = _qkv(p["attn"], cfg, h)
@@ -289,7 +289,7 @@ def lm_prefill(
                 "k": jnp.pad(kc, ((0, 0), (0, 0), (0, pad - s), (0, 0))).astype(cdt),
                 "v": jnp.pad(vc, ((0, 0), (0, 0), (0, pad - s), (0, 0))).astype(cdt),
             }
-        attn = flash_attention(
+        attn = attend(
             jnp.swapaxes(q, 1, 2), kc, vc, causal=True, window=window
         )
         attn = jnp.swapaxes(attn, 1, 2).reshape(x.shape[0], s, -1)
@@ -516,7 +516,6 @@ def lm_prefill_suffix(
     Asserted by tests, and the basis of the engine's prefix-on vs
     prefix-off byte parity.
     """
-    from repro.kernels.flash_attention.ops import flash_attention
     from repro.models.common import paged_view
 
     _require_no_windows(cfg)
@@ -547,7 +546,7 @@ def lm_prefill_suffix(
         v_view = paged_view(v_pool, view_tbl, block_size)
         # flash convention: queries are the LAST Sq positions of the key
         # sequence — with Skv = start + S that is exactly start..start+S-1
-        attn = flash_attention(
+        attn = attend(
             jnp.swapaxes(q, 1, 2), k_view, v_view, causal=True
         )
         attn = jnp.swapaxes(attn, 1, 2).reshape(x.shape[0], s, -1)

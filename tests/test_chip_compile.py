@@ -72,7 +72,8 @@ def test_hash_mix_compiles(one_chip, n, w):
     _compile_for_chip(hash_mix_pallas, ((n, w), jnp.uint32), sharding=one_chip)
 
 
-@pytest.mark.parametrize("q,rows,w,k", [(64, 32768, 32, 8), (8, 200, 32, 32)])
+@pytest.mark.parametrize("q,rows,w,k", [(64, 32768, 32, 8), (8, 200, 32, 32),
+                                         (8, 1000, 32, 200)])
 def test_tanimoto_compiles(one_chip, q, rows, w, k):
     from repro.kernels.tanimoto.kernel import tanimoto_blocks_pallas
 

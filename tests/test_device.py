@@ -29,7 +29,7 @@ def test_on_tpu_follows_jax_backend(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert device.on_tpu() is True
     assert IndexStore._similar_probe(None) == "device"
-    with pytest.raises(ValueError, match="forks"):
+    with pytest.raises(ValueError, match="process pool"):
         VerifyBatcher("process")
 
 

@@ -253,6 +253,43 @@ def test_verify_modes_agree_with_reference(corpus, targets, hashed_index,
     _assert_identical(serial_ref, res)
 
 
+PROCESS_VERIFY_AFTER_JAX = """
+import sys, tempfile
+from pathlib import Path
+import jax.numpy as jnp
+from repro.core import RecordStore, build_index, extract
+from repro.core.sdfgen import CorpusSpec, db_id_list, generate_corpus
+jnp.zeros(8).block_until_ready()  # JAX and its threads are live
+root = Path(tempfile.mkdtemp()) / "corpus"
+spec = CorpusSpec(n_files=2, records_per_file=200, key_bits=16)
+generate_corpus(root, spec)
+store = RecordStore(root)
+idx = build_index(store, key_mode="hashed_key", key_bits=16)
+targets = db_id_list(spec, "chembl")
+want = extract(store, idx, targets, key_bits=16, workers=0)
+got = extract(store, idx, targets, key_bits=16, workers=2,
+              verify_backend="process")
+assert got.records == want.records and got.mismatches == want.mismatches
+assert len(got.records) > 20
+print("OK")
+"""
+
+
+def test_process_verify_never_forks_a_live_jax_process(tmp_path):
+    """The process verify pool starts its workers as fresh interpreters, so
+    a parent that has JAX running is never forked (JAX warns on such a
+    fork: its threads do not survive it)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    proc = subprocess.run(
+        [sys.executable, "-W", "default", "-c", PROCESS_VERIFY_AFTER_JAX],
+        capture_output=True, text=True, env=env, timeout=300, cwd=tmp_path,
+    )
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    assert proc.stdout.strip().endswith("OK")
+    assert "fork()" not in proc.stderr, proc.stderr[-3000:]
+
+
 def test_verify_batcher_counts_batches():
     vb = VerifyBatcher("vector")
     stats = ReadStats()

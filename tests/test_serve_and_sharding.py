@@ -161,8 +161,8 @@ FLASH_MESH_PROG = textwrap.dedent(
     import json
     import jax, jax.numpy as jnp, numpy as np
     from repro.kernels.flash_attention.kernel import flash_attention_pallas
-    from repro.kernels.flash_attention.ops import flash_attention
     from repro.launch.mesh import make_mesh
+    from repro.models.common import attend
 
     rng = np.random.default_rng(0)
     mesh = make_mesh((2, 4), ("data", "model"))
@@ -172,7 +172,7 @@ FLASH_MESH_PROG = textwrap.dedent(
         k = jnp.asarray(rng.standard_normal((2, hkv, 40, 32)), jnp.float32)
         v = jnp.asarray(rng.standard_normal((2, hkv, 40, 32)), jnp.float32)
         want = flash_attention_pallas(q, k, v, interpret=True)
-        fn = jax.jit(lambda q, k, v: flash_attention(
+        fn = jax.jit(lambda q, k, v: attend(
             q, k, v, use_pallas=True, interpret=True))
         with jax.set_mesh(mesh):
             got = fn(q, k, v)

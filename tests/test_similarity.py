@@ -190,6 +190,25 @@ def test_kernel_round_quotient_corrects_an_inexact_divide(off):
     assert np.array_equal(got.view(np.int32), want.view(np.int32))
 
 
+def test_kernel_interpret_matches_oracle_past_one_heap_tile():
+    # k above 128 spans two lanes tiles of the running top-k
+    rng = np.random.default_rng(16)
+    q, db = _rand_plane(rng, 3, 4), _rand_plane(rng, 300, 4)
+    db[150] = db[7]  # a tie across the first tile's edge
+    ref = tanimoto_topk_ref(q, db, 200)
+    kern = tanimoto_topk(q, db, 200, interpret=True)
+    assert np.array_equal(ref[0], kern[0]) and np.array_equal(ref[1], kern[1])
+
+
+def test_store_refuses_fingerprints_the_kernel_cannot_score(tie_store_dir):
+    from repro.core.fingerprint import MAX_FP_BITS
+
+    sdir, idx = tie_store_dir
+    with pytest.raises(ValueError, match="fingerprint_bits"):
+        idx.save_sharded(sdir, n_shards=8, fingerprint_bits=2 * MAX_FP_BITS)
+    assert IndexStore.open(sdir).fingerprint_bits == DEFAULT_FP_BITS
+
+
 def test_kernel_refuses_fingerprints_wider_than_exact_scores_allow():
     from repro.kernels.tanimoto.kernel import MAX_BITS
 
