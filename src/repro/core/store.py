@@ -59,6 +59,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Set, Tuple
 import numpy as np
 
 from repro.device import on_tpu
+from repro.trace import span
 
 from .bloom import BloomFilter
 from .fingerprint import (
@@ -390,6 +391,8 @@ class QueryStats:
     verify_collisions: int = 0      # equal digest, different key (scanned past)
     similar_queries: int = 0        # fingerprint rows submitted to similar_batch
     fp_rows_scanned: int = 0        # query x database row pairs Tanimoto-scored
+    device_probes: int = 0          # shard probes run by the device kernel
+    upload_bytes: int = 0           # host->device bytes those probes copied
     shards_touched: Set[int] = field(default_factory=set)
 
     def merge(self, other: "QueryStats") -> None:
@@ -402,6 +405,8 @@ class QueryStats:
         self.verify_collisions += other.verify_collisions
         self.similar_queries += other.similar_queries
         self.fp_rows_scanned += other.fp_rows_scanned
+        self.device_probes += other.device_probes
+        self.upload_bytes += other.upload_bytes
         self.shards_touched |= other.shards_touched
 
 
@@ -697,11 +702,10 @@ class IndexStore:
         # so building it here would force every bitmap resident on a
         # store that promised O(touched shards)); otherwise each touched
         # shard probes its own lazily-loaded filter
-        passed_all = (
-            self._bloom_pass(q, sid)
-            if len(uniq) > 1 and self._bloom_plane is not None
-            else None
-        )
+        passed_all = None
+        if len(uniq) > 1 and self._bloom_plane is not None:
+            with span("store.bloom", keys=n):
+                passed_all = self._bloom_pass(q, sid)
 
         for gi in range(len(uniq)):
             s = int(uniq[gi])
@@ -711,7 +715,8 @@ class IndexStore:
             if passed_all is not None:
                 passed = passed_all[sel]
             else:
-                passed = self._bloom(s).contains(q[sel])
+                with span("store.bloom", shard=s, keys=len(sel)):
+                    passed = self._bloom(s).contains(q[sel])
             delta.bloom_rejects += int(len(sel) - passed.sum())
             sel = sel[passed]
             if not len(sel):
@@ -721,26 +726,29 @@ class IndexStore:
             qd = q[sel]
             td = shard.digests
             delta.digest_probes += int(len(sel))
-            if probe == "device":
-                found, starts = _probe_starts_device(td, qd)
-            else:
-                starts = np.searchsorted(td, qd, side="left")
-                inb = starts < len(td)
-                found = np.zeros(len(qd), dtype=bool)
-                found[inb] = td[starts[inb]] == qd[inb]
+            with span("store.probe", shard=s, keys=len(sel)):
+                if probe == "device":
+                    found, starts = _probe_starts_device(td, qd, delta)
+                else:
+                    starts = np.searchsorted(td, qd, side="left")
+                    inb = starts < len(td)
+                    found = np.zeros(len(qd), dtype=bool)
+                    found[inb] = td[starts[inb]] == qd[inb]
             delta.bloom_false_positives += int((~found).sum())
-            for j in np.nonzero(found)[0]:
-                row = int(sel[j])
-                kb = keys[row].encode()
-                t = int(starts[j])
-                while t < len(td) and td[t] == qd[j]:
-                    if shard.keys[t] == kb:
-                        file_ids[row] = shard.file_ids[t]
-                        offsets[row] = shard.offsets[t]
-                        hit[row] = True
-                        break
-                    delta.verify_collisions += 1  # digest collision
-                    t += 1
+            hits = np.nonzero(found)[0]
+            with span("store.verify", shard=s, keys=len(hits)):
+                for j in hits:
+                    row = int(sel[j])
+                    kb = keys[row].encode()
+                    t = int(starts[j])
+                    while t < len(td) and td[t] == qd[j]:
+                        if shard.keys[t] == kb:
+                            file_ids[row] = shard.file_ids[t]
+                            offsets[row] = shard.offsets[t]
+                            hit[row] = True
+                            break
+                        delta.verify_collisions += 1  # digest collision
+                        t += 1
 
         delta.hits = int(hit.sum())
         with self._stats_lock:
@@ -770,7 +778,8 @@ class IndexStore:
         crosses a shard boundary.
         """
         d_all, row_off, f_all, o_all = self._digest_plane
-        passed = self._bloom_pass(q, sid)
+        with span("store.bloom", keys=len(q)):
+            passed = self._bloom_pass(q, sid)
         delta.bloom_rejects += int(len(q) - passed.sum())
         sel = np.nonzero(passed)[0]
         if not len(sel):
@@ -784,49 +793,51 @@ class IndexStore:
             int(s) for s in np.unique(sid[sel])
         )
         qd = q[sel]
-        starts = np.searchsorted(d_all, qd, side="left")
-        inb = starts < len(d_all)
-        found = np.zeros(len(sel), dtype=bool)
-        found[inb] = d_all[starts[inb]] == qd[inb]
+        with span("store.probe", keys=len(sel)):
+            starts = np.searchsorted(d_all, qd, side="left")
+            inb = starts < len(d_all)
+            found = np.zeros(len(sel), dtype=bool)
+            found[inb] = d_all[starts[inb]] == qd[inb]
         delta.bloom_false_positives += int((~found).sum())
         fj = np.nonzero(found)[0]
         if not len(fj):
             return
-        frow = sel[fj]                  # batch rows with a digest hit
-        fpos = starts[fj]               # global plane positions (run heads)
-        fshard = (
-            np.searchsorted(row_off, fpos, side="right") - 1
-        ).astype(np.int64)
-        expected = np.array([keys[r].encode() for r in frow], dtype=np.bytes_)
-        ok = np.zeros(len(fj), dtype=bool)
-        for s in np.unique(fshard):
-            s = int(s)
-            g = np.nonzero(fshard == s)[0]
-            cand = self._shard(s).keys[fpos[g] - row_off[s]]  # one gather
-            ok[g] = cand == expected[g]
-        hrows = frow[ok]
-        file_ids[hrows] = f_all[fpos[ok]]
-        offsets[hrows] = o_all[fpos[ok]]
-        hit[hrows] = True
-        # First candidate mismatched: walk the equal-digest run (the
-        # Algorithm 3 collision discipline, scalar because it is rare).
-        for j in np.nonzero(~ok)[0]:
-            row = int(frow[j])
-            s = int(fshard[j])
-            shard = self._shard(s)
-            base = int(row_off[s])
-            end = int(row_off[s + 1])
-            kb = expected[j]
-            qdj = q[row]
-            t = int(fpos[j])
-            while t < end and d_all[t] == qdj:
-                if shard.keys[t - base] == kb:
-                    file_ids[row] = f_all[t]
-                    offsets[row] = o_all[t]
-                    hit[row] = True
-                    break
-                delta.verify_collisions += 1  # digest collision
-                t += 1
+        with span("store.verify", keys=len(fj)):
+            frow = sel[fj]                  # batch rows with a digest hit
+            fpos = starts[fj]               # global plane positions (run heads)
+            fshard = (
+                np.searchsorted(row_off, fpos, side="right") - 1
+            ).astype(np.int64)
+            expected = np.array([keys[r].encode() for r in frow], dtype=np.bytes_)
+            ok = np.zeros(len(fj), dtype=bool)
+            for s in np.unique(fshard):
+                s = int(s)
+                g = np.nonzero(fshard == s)[0]
+                cand = self._shard(s).keys[fpos[g] - row_off[s]]  # one gather
+                ok[g] = cand == expected[g]
+            hrows = frow[ok]
+            file_ids[hrows] = f_all[fpos[ok]]
+            offsets[hrows] = o_all[fpos[ok]]
+            hit[hrows] = True
+            # First candidate mismatched: walk the equal-digest run (the
+            # Algorithm 3 collision discipline, scalar because it is rare).
+            for j in np.nonzero(~ok)[0]:
+                row = int(frow[j])
+                s = int(fshard[j])
+                shard = self._shard(s)
+                base = int(row_off[s])
+                end = int(row_off[s + 1])
+                kb = expected[j]
+                qdj = q[row]
+                t = int(fpos[j])
+                while t < end and d_all[t] == qdj:
+                    if shard.keys[t - base] == kb:
+                        file_ids[row] = f_all[t]
+                        offsets[row] = o_all[t]
+                        hit[row] = True
+                        break
+                    delta.verify_collisions += 1  # digest collision
+                    t += 1
 
     # -- similarity modality ---------------------------------------------------
 
@@ -1041,23 +1052,27 @@ class IndexStore:
 
 
 def _probe_starts_device(
-    table_digests: np.ndarray, query_digests: np.ndarray
+    table_digests: np.ndarray, query_digests: np.ndarray, delta: QueryStats
 ) -> Tuple[np.ndarray, np.ndarray]:
     """Device digest probe: ``sorted_probe`` over (hi, lo) uint32 pairs.
 
     Returns ``(found, starts)`` with ``starts`` the leftmost equal-digest
     position — identical contract to the host ``searchsorted`` path, so the
-    equal-run verify loop above is backend-agnostic.
+    equal-run verify loop above is backend-agnostic.  Counts the call and
+    the bytes it copies to the device (table and queries) into ``delta``.
     """
     import jax.numpy as jnp
 
     from repro.kernels.sorted_probe.ops import sorted_probe
 
     td = np.ascontiguousarray(table_digests)
-    found, pos = sorted_probe(
-        jnp.asarray(_u64_to_pairs(query_digests)),
-        jnp.asarray(_u64_to_pairs(td)),
-    )
+    with span("store.upload", rows=len(td), keys=len(query_digests)):
+        q_pairs = _u64_to_pairs(query_digests)
+        t_pairs = _u64_to_pairs(td)
+        q_dev, t_dev = jnp.asarray(q_pairs), jnp.asarray(t_pairs)
+    delta.device_probes += 1
+    delta.upload_bytes += q_pairs.nbytes + t_pairs.nbytes
+    found, pos = sorted_probe(q_dev, t_dev)
     found = np.asarray(found, dtype=bool)
     starts = np.asarray(pos, dtype=np.int64)
     # The Pallas kernel's fence partitioning assumes a unique table; shard

@@ -99,10 +99,12 @@ def main():
             print(f"[{i}] prefill {r.prefill_s*1e3:.0f} ms, "
                   f"{r.tokens_per_s:.1f} tok/s → {r.text[:60]!r}")
         slo = eng.slo_ms()
+        c = eng.counters()
         print(f"slo: ttft p50 {slo['ttft_p50_ms']:.1f} ms / "
               f"p99 {slo['ttft_p99_ms']:.1f} ms, itl p50 "
-              f"{slo['itl_p50_ms']:.2f} ms / p99 {slo['itl_p99_ms']:.2f} ms")
-        c = eng.counters()
+              f"{slo['itl_p50_ms']:.2f} ms / p99 {slo['itl_p99_ms']:.2f} ms, "
+              f"mean admission wait "
+              f"{c['queue_wait_s'] / max(c['prefills'], 1) * 1e3:.1f} ms")
         if "pfx_entries" in c:
             print(f"prefix cache: hit rate {c['prefix_hit_rate']:.2f} "
                   f"({c['prefix_hits']:.0f}/"

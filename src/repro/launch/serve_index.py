@@ -439,10 +439,13 @@ def main():
           f"mean batch {sch['mean_batch_keys']:.1f} keys (max "
           f"{sch['batch_keys_max']}), flushes full={sch['full_flushes']} "
           f"cohort={sch['cohort_flushes']} deadline={sch['deadline_flushes']} "
-          f"immediate={sch['immediate_flushes']}")
+          f"immediate={sch['immediate_flushes']}, mean queue wait "
+          f"{sch['latency_ms']['mean_wait']:.2f} ms")
     print(f"store: {st['bloom_rejects']} bloom rejects, "
           f"{st['verify_collisions']} digest collisions verified away, "
-          f"{st['shards_touched']}/{svc.router.n_shards} shards touched")
+          f"{st['shards_touched']}/{svc.router.n_shards} shards touched, "
+          f"{st['upload_bytes'] / max(sch['batches'], 1) / 1e6:.3f} MB "
+          f"uploaded per batch ({st['device_probes']} device probes)")
     print(f"cache: {cache['hit_rate']:.0%} hit rate, "
           f"{cache['protected']} protected / {cache['probation']} probation "
           f"entries")

@@ -47,7 +47,13 @@ uniform batch the two engines produce identical ``token_ids``
 
 Per-request SLO accounting records time-to-first-token (submit → prefill
 argmax) and inter-token latency (consecutive decode materializations) in
-bounded windows; ``slo_ms()`` reports p50/p99 of both.
+bounded windows; ``slo_ms()`` reports p50/p99 of both.  The time each
+admitted request waited in the queue adds to
+``ContinuousStats.queue_wait_s``.  The loop's host work is in profiler
+spans (:mod:`repro.trace`): ``engine.admit`` around each admission, with
+``engine.prefill`` (dispatch through the first token on the host) inside
+it, and per decode step ``engine.step`` (input upload, dispatch, the
+host sync) then ``engine.emit`` (emit and evict).
 """
 
 from __future__ import annotations
@@ -70,6 +76,7 @@ from repro.serve.engine import GenerationResult, ServeConfig
 from repro.serve.kvcache import (
     BlockManager, PagedCacheSpec, PrefixIndex, blocks_for,
 )
+from repro.trace import span
 
 __all__ = ["ContinuousEngine", "ContinuousStats", "EngineClosed"]
 
@@ -98,6 +105,7 @@ class ContinuousStats:
     prefix_hits: int = 0        # admissions that adopted indexed blocks
     prefix_misses: int = 0      # prefix-eligible admissions with no match
     prefill_tokens_saved: int = 0  # prompt tokens whose prefill was skipped
+    queue_wait_s: float = 0.0   # sum over admissions of (admission - submit)
 
     @property
     def tokens_per_step(self) -> float:
@@ -131,12 +139,13 @@ class _Seq:
 
 
 class _Request:
-    __slots__ = ("prompt", "budget", "future", "t_submit", "seed")
+    __slots__ = ("prompt", "budget", "future", "t_submit", "seed", "rid")
 
     def __init__(self, prompt: List[int], budget: int, seed: int = 0):
         self.prompt = prompt
         self.budget = budget
         self.seed = seed
+        self.rid = 0            # submission ordinal, set under the lock
         self.future: "Future[GenerationResult]" = Future()
         self.t_submit = time.perf_counter()
 
@@ -307,6 +316,7 @@ class ContinuousEngine:
             if self._stop:
                 raise EngineClosed("engine is closed")
             self.stats.requests += 1
+            req.rid = self.stats.requests
             req.seed = seed if seed is not None else (
                 self.scfg.seed + self.stats.requests
             )
@@ -435,7 +445,8 @@ class ContinuousEngine:
                 with self._lock:
                     self.stats.cancelled += 1
                 continue
-            self._admit_one(req, total, adopt, start)
+            with span("engine.admit", request=req.rid):
+                self._admit_one(req, total, adopt, start)
         # no free slot for the head request: wait for an eviction
 
     def _admit_one(
@@ -454,41 +465,44 @@ class ContinuousEngine:
             blocks_for(L, self.spec.block_size) * self.spec.block_size,
         )
         t0 = time.perf_counter()
+        self.stats.queue_wait_s += t0 - req.t_submit
         slot: Optional[int] = None
-        if start > 0:
-            # Prefix hit: the slot and its blocks come first (suffix
-            # prefill writes through the block table), then only the
-            # unmatched tail runs the model — ``start`` prompt tokens
-            # cost zero prefill FLOPs.
-            slot = self._free_slots.pop()
-            admitted = self._mgr.admit(slot, total, prefix_blocks=adopt)
-            assert admitted, "can_admit passed but admit failed (leader is sole allocator)"
-            suf = np.full((1, bucket - start), self.tok.pad_id, np.int32)
-            suf[0, : L - start] = prompt[start:]
-            row = jnp.asarray(self._mgr.tables[slot])
-            logits, self._cache = self._prefill_suffix(
-                self.params, jnp.asarray(suf), start, row, self._cache,
-                jnp.asarray([L - start], jnp.int32),
-            )
-            dense = None
-            self.stats.prefix_hits += 1
-            self.stats.prefill_tokens_saved += start
-        else:
-            if self._prefix_enabled:
-                self.stats.prefix_misses += 1
-            toks = np.full((1, bucket), self.tok.pad_id, np.int32)
-            toks[0, :L] = prompt
-            batch: Dict[str, Any] = {
-                "tokens": jnp.asarray(toks),
-                "lengths": jnp.asarray([L], jnp.int32),
-            }
-            if self.cfg.family == "vlm":
-                batch["patch_embeds"] = jnp.zeros(
-                    (1, self.cfg.n_img_tokens, self.cfg.d_model), jnp.float32
+        with span("engine.prefill", request=req.rid, prompt_len=L,
+                  bucket=bucket):
+            if start > 0:
+                # Prefix hit: the slot and its blocks come first (suffix
+                # prefill writes through the block table), then only the
+                # unmatched tail runs the model — ``start`` prompt tokens
+                # cost zero prefill FLOPs.
+                slot = self._free_slots.pop()
+                admitted = self._mgr.admit(slot, total, prefix_blocks=adopt)
+                assert admitted, "can_admit passed but admit failed (leader is sole allocator)"
+                suf = np.full((1, bucket - start), self.tok.pad_id, np.int32)
+                suf[0, : L - start] = prompt[start:]
+                row = jnp.asarray(self._mgr.tables[slot])
+                logits, self._cache = self._prefill_suffix(
+                    self.params, jnp.asarray(suf), start, row, self._cache,
+                    jnp.asarray([L - start], jnp.int32),
                 )
-            logits, dense = self._prefill(self.params, batch)
-        first = self._first_token(logits, req.seed)
-        now = time.perf_counter()
+                dense = None
+                self.stats.prefix_hits += 1
+                self.stats.prefill_tokens_saved += start
+            else:
+                if self._prefix_enabled:
+                    self.stats.prefix_misses += 1
+                toks = np.full((1, bucket), self.tok.pad_id, np.int32)
+                toks[0, :L] = prompt
+                batch: Dict[str, Any] = {
+                    "tokens": jnp.asarray(toks),
+                    "lengths": jnp.asarray([L], jnp.int32),
+                }
+                if self.cfg.family == "vlm":
+                    batch["patch_embeds"] = jnp.zeros(
+                        (1, self.cfg.n_img_tokens, self.cfg.d_model), jnp.float32
+                    )
+                logits, dense = self._prefill(self.params, batch)
+            first = self._first_token(logits, req.seed)
+            now = time.perf_counter()
         prefill_s = now - t0
         self.stats.prefills += 1
         with self._lock:
@@ -547,37 +561,40 @@ class ContinuousEngine:
 
     def _decode_once(self) -> None:
         """One batched paged decode step + host-side emit/evict."""
-        if self._tables_dirty:
-            self._tables_dev = jnp.asarray(self._mgr.tables)
-            self._tables_dirty = False
-        args = (
-            self.params,
-            jnp.asarray(self._cur),
-            jnp.asarray(self._pos),
-            self._tables_dev,
-            self._cache,
-        )
-        if not self.scfg.greedy:
-            args = args + (jnp.asarray(self._seeds), jnp.asarray(self._idx))
-        nxt, self._cache = self._step(*args)
-        nxt = np.asarray(nxt)  # the one host sync per step: (S,) int32
-        now = time.perf_counter()
-        self.stats.steps += 1
-        for slot, seq in list(self._active.items()):
-            tok = int(nxt[slot])
-            seq.fed += 1
-            seq.tokens.append(tok)
-            with self._lock:
-                self._itl_ms.append((now - seq.t_last) * 1e3)
-            seq.t_last = now
-            self.stats.tokens_out += 1
-            self.stats.decode_tokens += 1
-            if tok == self.tok.eos_id or len(seq.tokens) >= seq.budget:
-                self._evict(slot, seq, now)
-            else:
-                self._cur[slot, 0] = tok
-                self._pos[slot] += 1
-                self._idx[slot] = len(seq.tokens)
+        step = self.stats.steps + 1
+        with span("engine.step", step=step, active=len(self._active)):
+            if self._tables_dirty:
+                self._tables_dev = jnp.asarray(self._mgr.tables)
+                self._tables_dirty = False
+            args = (
+                self.params,
+                jnp.asarray(self._cur),
+                jnp.asarray(self._pos),
+                self._tables_dev,
+                self._cache,
+            )
+            if not self.scfg.greedy:
+                args = args + (jnp.asarray(self._seeds), jnp.asarray(self._idx))
+            nxt, self._cache = self._step(*args)
+            nxt = np.asarray(nxt)  # the one host sync per step: (S,) int32
+            now = time.perf_counter()
+        self.stats.steps = step
+        with span("engine.emit", step=step):
+            for slot, seq in list(self._active.items()):
+                tok = int(nxt[slot])
+                seq.fed += 1
+                seq.tokens.append(tok)
+                with self._lock:
+                    self._itl_ms.append((now - seq.t_last) * 1e3)
+                seq.t_last = now
+                self.stats.tokens_out += 1
+                self.stats.decode_tokens += 1
+                if tok == self.tok.eos_id or len(seq.tokens) >= seq.budget:
+                    self._evict(slot, seq, now)
+                else:
+                    self._cur[slot, 0] = tok
+                    self._pos[slot] += 1
+                    self._idx[slot] = len(seq.tokens)
 
     def _evict(self, slot: int, seq: _Seq, now: float) -> None:
         self._mgr.release(slot)

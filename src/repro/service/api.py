@@ -638,6 +638,8 @@ class QueryService:
                 "digest_probes": qs.digest_probes,
                 "verify_collisions": qs.verify_collisions,
                 "shards_touched": len(qs.shards_touched),
+                "device_probes": qs.device_probes,
+                "upload_bytes": qs.upload_bytes,
             },
             "similarity": {
                 "fingerprint_bits": self.router.fingerprint_bits,
@@ -674,6 +676,8 @@ class QueryService:
                 "coalesced_requests": ss.coalesced_requests,
                 "cancelled": ss.cancelled,
                 "leader_deaths": ss.leader_deaths,
+                "requests_flushed": ss.requests_flushed,
+                "queue_wait_s": ss.queue_wait_s,
                 "latency_ms": lat,
             },
             "cache": {

@@ -73,6 +73,7 @@ from repro.core.store import (
     shard_of,
 )
 from repro.runtime.fault import BackoffPolicy
+from repro.trace import current_batch, in_batch, span
 
 from .health import REPLICA_WIDE, HealthTracker
 from .transport import (
@@ -454,14 +455,15 @@ class ShardRouter:
         with self._stats_lock:
             self.stats.batches += 1
             self.stats.keys += n
-        if not self._ft_active():
-            try:
-                return self._healthy_lookup(keys, q)
-            except TransportError:
-                # an endpoint failed mid-probe: re-route this batch
-                # through the per-shard failure-domain path
-                pass
-        return self._ft_lookup(keys, q)
+        with span("router.lookup", keys=n):
+            if not self._ft_active():
+                try:
+                    return self._healthy_lookup(keys, q)
+                except TransportError:
+                    # an endpoint failed mid-probe: re-route this batch
+                    # through the per-shard failure-domain path
+                    pass
+            return self._ft_lookup(keys, q)
 
     def lookup_batch(
         self, keys: Sequence[str], digests: Optional[np.ndarray] = None
@@ -508,11 +510,16 @@ class ShardRouter:
             fid, off, hit = tr.lookup_all(keys, q)
             return LookupBatchResult(fid, off, hit, no_degrade)
 
+        batch = current_batch()
+
         def probe_group(shard: int, sel: np.ndarray):
             tr = self._transports[self._next_replica()]
-            return tr.lookup_shard(
-                shard, [keys[i] for i in sel], q[sel]
-            )
+            with in_batch(batch), span(
+                "router.shard", shard=shard, keys=len(sel)
+            ):
+                return tr.lookup_shard(
+                    shard, [keys[i] for i in sel], q[sel]
+                )
 
         file_ids = np.full(n, -1, dtype=np.int32)
         offsets = np.full(n, -1, dtype=np.int64)
@@ -553,13 +560,23 @@ class ShardRouter:
         hit = np.zeros(n, dtype=bool)
         degraded = np.zeros(n, dtype=bool)
 
+        batch = current_batch()
+
+        def probe_shard(tr, timeout_s, shard, klist, dg):
+            # runs on a probe-pool thread: the batch id comes along
+            with in_batch(batch):
+                return tr.lookup_shard(shard, klist, dg, timeout_s)
+
         def probe_group(shard: int, sel: np.ndarray):
             klist = [keys[i] for i in sel]
             dg = q[sel]
-            return self._ft_probe(
-                shard,
-                lambda tr, to: tr.lookup_shard(shard, klist, dg, to),
-            )
+            with in_batch(batch), span(
+                "router.shard", shard=shard, keys=len(sel)
+            ):
+                return self._ft_probe(
+                    shard,
+                    lambda tr, to: probe_shard(tr, to, shard, klist, dg),
+                )
 
         futs = {
             self._gather.submit(probe_group, s, sel): (s, sel)

@@ -45,8 +45,11 @@ Flush taxonomy (counted in :class:`SchedulerStats`):
 
 Requests are admitted whole (a request's keys never split across
 batches), results scatter back as zero-copy row slices of the batch
-arrays, and per-request latency (queue wait + total) is accounted in a
-bounded window for the service's p50/p99 rows.
+arrays, each request's queue wait (submit to flush) adds to
+``SchedulerStats.queue_wait_s``, and its total latency is kept in a
+bounded window for the service's p50/p99 rows.  Each executed batch is
+one ``service.batch`` profiler span (:mod:`repro.trace`), and every span
+its probe opens, on any thread, carries the batch's id.
 
 **Leader-death containment.**  Probes run on client threads, so a probe
 that raises tears down a *client*, not a service worker — the batcher
@@ -63,6 +66,7 @@ dead.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import deque
@@ -71,6 +75,8 @@ from dataclasses import dataclass
 from typing import Callable, Deque, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.trace import in_batch, span
 
 __all__ = ["BatchResult", "MicroBatcher", "SchedulerStats"]
 
@@ -113,10 +119,18 @@ class SchedulerStats:
     cancelled: int = 0          # requests cancelled before probing
     leader_deaths: int = 0      # in-flight cohorts whose leader thread died
     batch_keys_max: int = 0
+    requests_flushed: int = 0   # requests taken into an executed batch
+    queue_wait_s: float = 0.0   # sum over those of (flush - submit)
 
     @property
     def mean_batch_keys(self) -> float:
         return self.keys_flushed / self.batches if self.batches else 0.0
+
+    @property
+    def mean_wait_s(self) -> float:
+        """Mean queue wait of a flushed request, all-time."""
+        return (self.queue_wait_s / self.requests_flushed
+                if self.requests_flushed else 0.0)
 
 
 class _Request:
@@ -159,7 +173,7 @@ class MicroBatcher:
         self.max_wait = max_wait_ms / 1e3
         self.close_grace_s = float(close_grace_s)
         self.stats = SchedulerStats()
-        self.wait_seconds: Deque[float] = deque(maxlen=_LATENCY_WINDOW)
+        self._batch_ids = itertools.count(1)      # the spans' batch stat
         self.total_seconds: Deque[float] = deque(maxlen=_LATENCY_WINDOW)
         self._lock = threading.Lock()    # queue, arming state, counters
         self._leader = threading.Lock()  # at most one probing thread
@@ -344,25 +358,32 @@ class MicroBatcher:
         with self._lock:
             self._inflight = batch
             self._leader_thread = threading.current_thread()
+            self.stats.requests_flushed += len(batch)
+            self.stats.queue_wait_s += sum(t_flush - r.t_submit for r in batch)
         try:
-            try:
-                cols = self.probe_fn(all_keys)
-            except BaseException as e:  # noqa: BLE001 — delivered first
+            with in_batch(next(self._batch_ids)), span(
+                "service.batch", keys=len(all_keys), requests=len(batch),
+                reason=reason,
+            ):
+                try:
+                    cols = self.probe_fn(all_keys)
+                except BaseException as e:  # noqa: BLE001 — delivered first
+                    for req in batch:
+                        if not req.future.done():
+                            req.future.set_exception(e)
+                    if isinstance(e, (SystemExit, KeyboardInterrupt)):
+                        raise  # shutdown intent: unwind the leader thread too
+                    return
+                t_done = time.monotonic()
+                # rebuild each request's rows with the probe's own result
+                # type (a NamedTuple like LookupBatchResult survives the
+                # slicing)
+                remake = getattr(type(cols), "_make", tuple)
+                row = 0
                 for req in batch:
-                    if not req.future.done():
-                        req.future.set_exception(e)
-                if isinstance(e, (SystemExit, KeyboardInterrupt)):
-                    raise  # shutdown intent: unwind the leader thread too
-                return
-            t_done = time.monotonic()
-            # rebuild each request's rows with the probe's own result type
-            # (a NamedTuple like LookupBatchResult survives the slicing)
-            remake = getattr(type(cols), "_make", tuple)
-            row = 0
-            for req in batch:
-                stop = row + len(req.keys)
-                req.future.set_result(remake(c[row:stop] for c in cols))
-                row = stop
+                    stop = row + len(req.keys)
+                    req.future.set_result(remake(c[row:stop] for c in cols))
+                    row = stop
         finally:
             with self._lock:
                 self._inflight = None
@@ -401,23 +422,24 @@ class MicroBatcher:
         setattr(st, st_field, getattr(st, st_field) + 1)
         with self._lock:  # latency_ms snapshots these under the same lock
             for req in batch:
-                self.wait_seconds.append(req.t_flush - req.t_submit)
                 self.total_seconds.append(t_done - req.t_submit)
 
     # -- latency accounting --------------------------------------------------
 
     def latency_ms(self, percentiles: Sequence[float] = (50, 99)) -> dict:
-        """Request-latency percentiles over the bounded window."""
+        """Request-latency percentiles over the bounded window, and the
+        all-time mean queue wait (``mean_wait``)."""
         with self._lock:
             total = list(self.total_seconds)
-            waits = list(self.wait_seconds)
+            mean_wait = self.stats.mean_wait_s * 1e3
         if not total:
-            return {f"p{int(p)}": 0.0 for p in percentiles} | {"mean_wait": 0.0}
+            return {f"p{int(p)}": 0.0 for p in percentiles} | {
+                "mean_wait": mean_wait}
         out = {
             f"p{int(p)}": float(np.percentile(total, p)) * 1e3
             for p in percentiles
         }
-        out["mean_wait"] = float(np.mean(waits)) * 1e3
+        out["mean_wait"] = mean_wait
         return out
 
     # -- shutdown ------------------------------------------------------------

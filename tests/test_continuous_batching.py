@@ -272,6 +272,24 @@ def test_pool_exhaustion_is_admission_backpressure(tiny, ref_engine):
     cont.close()
 
 
+def test_admission_wait_counts_time_in_the_queue(tiny):
+    """``queue_wait_s`` adds each admitted request's submit-to-admission
+    time: with room for one sequence, the second waits out the first."""
+    cfg, params = tiny
+    cont = ContinuousEngine(
+        cfg, params,
+        _spec(n_blocks=6, max_slots=2, max_blocks_per_seq=4),
+        ServeConfig(max_new_tokens=12, max_len=32),
+    )
+    futs = [cont.submit(t, 12, lead=False) for t in ("InChI=1S/C4", "C1=CC")]
+    cont._maybe_lead()
+    first, _ = [f.result(timeout=300) for f in futs]
+    c = cont.counters()
+    assert c["prefills"] == 2
+    assert c["queue_wait_s"] >= first.prefill_s + first.decode_s
+    cont.close()
+
+
 def test_oversized_request_fails_cleanly(tiny):
     cfg, params = tiny
     cont = ContinuousEngine(

@@ -187,6 +187,28 @@ def test_cohort_flush_fires_on_target_arrival():
     mb.close()
 
 
+def test_queue_wait_is_counted_once_per_flushed_request():
+    """Each flushed request adds (flush - submit) to ``queue_wait_s``;
+    ``latency_ms()["mean_wait"]`` is that sum over flushed requests."""
+    mb, release, probing, calls = _blocked_batcher(max_batch=8)
+    t = threading.Thread(target=lambda: mb.lookup(["k/0"]))
+    t.start()
+    assert probing.wait(10)
+    futs = [mb.submit([f"k/{i}"]) for i in range(1, 4)]
+    time.sleep(0.05)            # the three wait behind the blocked probe
+    release.set()
+    t.join(10)
+    for f in futs:
+        f.result(timeout=10)
+    mb.close()
+    st = mb.stats
+    assert st.requests_flushed == 4 and st.batches == 2
+    assert st.queue_wait_s >= 3 * 0.05
+    assert mb.latency_ms()["mean_wait"] == pytest.approx(
+        st.queue_wait_s / 4 * 1e3)
+    assert not hasattr(mb, "wait_seconds")
+
+
 def test_shutdown_cancels_queued_futures():
     mb, release, probing, calls = _blocked_batcher()
     t = threading.Thread(target=lambda: mb.lookup(["k/0"]))

@@ -243,6 +243,24 @@ def test_device_probe_parity(tmp_path):
         host.lookup_batch(keys[:1], probe="quantum")
 
 
+def test_device_probe_counts_its_upload(tmp_path):
+    """The device probe copies each touched shard's whole digest column and
+    the batch's queries to the device, as (hi, lo) uint32 pairs: 8 bytes a
+    row and a query, counted in ``upload_bytes``, one ``device_probes``
+    per touched shard.  The host probe copies nothing."""
+    idx = synth_index(1500)
+    idx.save_sharded(tmp_path / "s", n_shards=4)
+    keys = [f"InChI=1S/synthetic/{i}" for i in range(0, 1500, 11)]
+    store = IndexStore.open(tmp_path / "s")
+    store.lookup_batch(keys, probe="host")
+    assert (store.stats.device_probes, store.stats.upload_bytes) == (0, 0)
+    store.lookup_batch(keys, probe="device")
+    touched = np.unique(shard_of(digest_u64(keys), 4))
+    rows = sum(int(store.manifest["shards"][int(s)]["count"]) for s in touched)
+    assert store.stats.device_probes == len(touched)
+    assert store.stats.upload_bytes == 8 * (rows + len(keys))
+
+
 @settings(max_examples=15)
 @given(picks=st.lists(st.integers(min_value=0, max_value=2999), min_size=1,
                       max_size=60))
