@@ -26,7 +26,8 @@ Query model (batch-first — ``lookup_batch(keys)``):
 3. **Bloom prefilter** per shard — misses are rejected from a few bit
    probes without ever faulting the shard's data columns in;
 4. **probe** survivors against the shard's sorted digest column — host
-   ``np.searchsorted`` or the ``sorted_probe`` Pallas kernel on device;
+   ``np.searchsorted`` or the ``sorted_probe`` Pallas kernel on device,
+   every touched shard's probe dispatched before one fetch of them all;
 5. **verify** every digest hit against the full key, scanning forward over
    the equal-digest run (Algorithm 3 discipline: a digest collision costs
    an extra compare, never a wrong record).
@@ -393,6 +394,7 @@ class QueryStats:
     fp_rows_scanned: int = 0        # query x database row pairs Tanimoto-scored
     device_probes: int = 0          # shard probes run by the device kernel
     upload_bytes: int = 0           # host->device bytes those probes copied
+    device_syncs: int = 0           # host waits for those probes: one a batch
     shards_touched: Set[int] = field(default_factory=set)
 
     def merge(self, other: "QueryStats") -> None:
@@ -407,6 +409,7 @@ class QueryStats:
         self.fp_rows_scanned += other.fp_rows_scanned
         self.device_probes += other.device_probes
         self.upload_bytes += other.upload_bytes
+        self.device_syncs += other.device_syncs
         self.shards_touched |= other.shards_touched
 
 
@@ -707,6 +710,10 @@ class IndexStore:
             with span("store.bloom", keys=n):
                 passed_all = self._bloom_pass(q, sid)
 
+        # probe every touched shard first, verify after: on the device the
+        # probes queue behind each other and come back in one host sync
+        probed = []   # (shard id, shard, batch rows, digests) per touched shard
+        results = []  # (found, starts) per touched shard; device arrays until fetched
         for gi in range(len(uniq)):
             s = int(uniq[gi])
             lo = group_starts[gi]
@@ -728,12 +735,25 @@ class IndexStore:
             delta.digest_probes += int(len(sel))
             with span("store.probe", shard=s, keys=len(sel)):
                 if probe == "device":
-                    found, starts = _probe_starts_device(td, qd, delta)
+                    results.append(_dispatch_probe_device(td, qd, delta))
                 else:
                     starts = np.searchsorted(td, qd, side="left")
                     inb = starts < len(td)
                     found = np.zeros(len(qd), dtype=bool)
                     found[inb] = td[starts[inb]] == qd[inb]
+                    results.append((found, starts))
+            probed.append((s, shard, sel, qd))
+
+        if probe == "device" and probed:
+            with span("store.probe", shard=probed[0][0],
+                      keys=sum(len(p[2]) for p in probed), shards=len(probed)):
+                results = _collect_probes_device(
+                    [p[1].digests for p in probed], [p[3] for p in probed], results
+                )
+            delta.device_syncs += 1
+
+        for (s, shard, sel, qd), (found, starts) in zip(probed, results):
+            td = shard.digests
             delta.bloom_false_positives += int((~found).sum())
             hits = np.nonzero(found)[0]
             with span("store.verify", shard=s, keys=len(hits)):
@@ -1051,15 +1071,14 @@ class IndexStore:
         )
 
 
-def _probe_starts_device(
-    table_digests: np.ndarray, query_digests: np.ndarray, delta: QueryStats
-) -> Tuple[np.ndarray, np.ndarray]:
-    """Device digest probe: ``sorted_probe`` over (hi, lo) uint32 pairs.
+def _dispatch_probe_device(table_digests: np.ndarray, query_digests: np.ndarray,
+                           delta: QueryStats):
+    """Upload one shard's digest column and queries as (hi, lo) uint32 pairs
+    and dispatch ``sorted_probe`` on them, without waiting for the result.
 
-    Returns ``(found, starts)`` with ``starts`` the leftmost equal-digest
-    position — identical contract to the host ``searchsorted`` path, so the
-    equal-run verify loop above is backend-agnostic.  Counts the call and
-    the bytes it copies to the device (table and queries) into ``delta``.
+    Returns the device ``(found, pos)``, for :func:`_collect_probes_device`.
+    Counts the call and the bytes it copies to the device (table and
+    queries) into ``delta``.
     """
     import jax.numpy as jnp
 
@@ -1072,16 +1091,33 @@ def _probe_starts_device(
         q_dev, t_dev = jnp.asarray(q_pairs), jnp.asarray(t_pairs)
     delta.device_probes += 1
     delta.upload_bytes += q_pairs.nbytes + t_pairs.nbytes
-    found, pos = sorted_probe(q_dev, t_dev)
-    found = np.asarray(found, dtype=bool)
-    starts = np.asarray(pos, dtype=np.int64)
-    # The Pallas kernel's fence partitioning assumes a unique table; shard
-    # digest columns carry collision runs, and a run straddling a table
-    # block gives a within-block (not global-leftmost) position.  Rewind to
-    # the run head so the forward verify scan sees every candidate.
-    for j in np.nonzero(found)[0]:
-        t = int(starts[j])
-        while t > 0 and td[t - 1] == query_digests[j]:
-            t -= 1
-        starts[j] = t
-    return found, starts
+    return sorted_probe(q_dev, t_dev)
+
+
+def _collect_probes_device(
+    tables: Sequence[np.ndarray],
+    queries: Sequence[np.ndarray],
+    pending: Sequence[Tuple[object, object]],
+) -> List[Tuple[np.ndarray, np.ndarray]]:
+    """Fetch every dispatched probe in one host sync: ``(found, starts)`` per
+    shard, with ``starts`` the leftmost equal-digest position — the same
+    contract as the host ``searchsorted`` path, so the equal-run verify loop
+    is backend-agnostic."""
+    import jax
+
+    out = []
+    for td, qd, (found, pos) in zip(tables, queries, jax.device_get(list(pending))):
+        found = np.asarray(found, dtype=bool)
+        starts = np.asarray(pos, dtype=np.int64)
+        # The Pallas kernel's fence partitioning assumes a unique table;
+        # shard digest columns carry collision runs, and a run straddling a
+        # table block gives a within-block (not global-leftmost) position.
+        # Rewind to the run head so the forward verify scan sees every
+        # candidate.
+        for j in np.nonzero(found)[0]:
+            t = int(starts[j])
+            while t > 0 and td[t - 1] == qd[j]:
+                t -= 1
+            starts[j] = t
+        out.append((found, starts))
+    return out

@@ -445,7 +445,8 @@ def main():
           f"{st['verify_collisions']} digest collisions verified away, "
           f"{st['shards_touched']}/{svc.router.n_shards} shards touched, "
           f"{st['upload_bytes'] / max(sch['batches'], 1) / 1e6:.3f} MB "
-          f"uploaded per batch ({st['device_probes']} device probes)")
+          f"uploaded per batch ({st['device_probes']} device probes in "
+          f"{st['device_syncs']} syncs)")
     print(f"cache: {cache['hit_rate']:.0%} hit rate, "
           f"{cache['protected']} protected / {cache['probation']} probation "
           f"entries")

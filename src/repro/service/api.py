@@ -640,6 +640,7 @@ class QueryService:
                 "shards_touched": len(qs.shards_touched),
                 "device_probes": qs.device_probes,
                 "upload_bytes": qs.upload_bytes,
+                "device_syncs": qs.device_syncs,
             },
             "similarity": {
                 "fingerprint_bits": self.router.fingerprint_bits,

@@ -261,6 +261,96 @@ def test_device_probe_counts_its_upload(tmp_path):
     assert store.stats.upload_bytes == 8 * (rows + len(keys))
 
 
+def test_device_batch_syncs_once_for_all_its_shards(tmp_path):
+    """A device batch dispatches every touched shard's probe, then waits
+    for all of them in one host sync: ``device_syncs`` rises by 1 a batch
+    however many shards it touched, ``device_probes`` by their number."""
+    idx = synth_index(1500)
+    idx.save_sharded(tmp_path / "s", n_shards=8)
+    keys = [f"InChI=1S/synthetic/{i}" for i in range(0, 1500, 13)]
+    touched = np.unique(shard_of(digest_u64(keys), 8))
+    assert len(touched) >= 3
+    store = IndexStore.open(tmp_path / "s")
+    rows = sum(int(store.manifest["shards"][int(s)]["count"]) for s in touched)
+    store.lookup_batch(keys, probe="host")
+    assert store.stats.device_syncs == 0
+    for batch in (1, 2):
+        store.lookup_batch(keys, probe="device")
+        assert store.stats.device_syncs == batch
+        assert store.stats.device_probes == batch * len(touched)
+        assert store.stats.upload_bytes == batch * 8 * (rows + len(keys))
+    store.lookup_batch([], probe="device")  # nothing probed, nothing to wait for
+    assert store.stats.device_syncs == 2
+
+
+def test_pipelined_device_probe_parity_across_collision_runs(tmp_path, monkeypatch):
+    """At 12 digest bits over 4 shards every shard of one batch holds
+    equal-digest runs, and some run straddles the kernel's 2,048-row table
+    block, where the Pallas kernel answers a within-block position.  With
+    the kernel itself (interpreted) behind ``sorted_probe``, the pipelined
+    device path must resolve exactly what the host path does."""
+    from repro.kernels.sorted_probe import ops
+    from repro.kernels.sorted_probe.kernel import DEFAULT_TABLE_BLOCK as bt
+
+    monkeypatch.setattr(
+        ops, "sorted_probe", lambda q, t: ops.sorted_probe_pallas(q, t, interpret=True)
+    )
+    idx = synth_index(10000, n_files=5)
+    idx.save_sharded(tmp_path / "s", n_shards=4, digest_bits=12)
+    host = IndexStore.open(tmp_path / "s")
+    dev = IndexStore.open(tmp_path / "s")
+    straddling = [
+        s for s in range(4)
+        if len(d := host._shard(s).digests) > bt and d[bt - 1] == d[bt]
+    ]
+    assert straddling, "no collision run crosses a table block"
+    keys = list(idx.entries.keys())[::3]
+    for s in straddling:  # every key of each straddling run
+        d, kk = host._shard(s).digests, host._shard(s).keys
+        lo = int(np.searchsorted(d, d[bt], side="left"))
+        hi = int(np.searchsorted(d, d[bt], side="right"))
+        assert lo < bt < hi
+        keys += [k.decode() for k in kk[lo:hi]]
+    keys += [f"InChI=1S/absent/{i}" for i in range(300)]
+    want = host.lookup_batch(keys, probe="host")
+    got = dev.lookup_batch(keys, probe="device")
+    for w, g in zip(want, got):
+        np.testing.assert_array_equal(w, g)
+    assert dev.stats.device_syncs == 1 and dev.stats.device_probes == 4
+    assert dev.stats.verify_collisions == host.stats.verify_collisions > 0
+    assert dev.stats.hits == host.stats.hits
+
+
+def test_failed_dispatch_leaves_the_batch_and_the_stats(tmp_path, monkeypatch):
+    """A probe that raises on the k-th dispatch of a batch propagates out of
+    ``lookup_batch`` (the router scores an endpoint bug as a bug, not as a
+    degraded shard) and merges none of the batch's counts."""
+    import copy
+
+    from repro.kernels.sorted_probe import ops
+
+    idx = synth_index(1500)
+    idx.save_sharded(tmp_path / "s", n_shards=8)
+    keys = [f"InChI=1S/synthetic/{i}" for i in range(0, 1500, 13)]
+    assert len(np.unique(shard_of(digest_u64(keys), 8))) >= 3
+    store = IndexStore.open(tmp_path / "s")
+    store.lookup_batch(keys, probe="device")
+    before = copy.deepcopy(store.stats)
+    real, calls = ops.sorted_probe, []
+
+    def third_fails(q, t):
+        calls.append(len(t))
+        if len(calls) == 3:
+            raise RuntimeError("probe endpoint bug")
+        return real(q, t)
+
+    monkeypatch.setattr(ops, "sorted_probe", third_fails)
+    with pytest.raises(RuntimeError, match="probe endpoint bug"):
+        store.lookup_batch(keys, probe="device")
+    assert len(calls) == 3
+    assert store.stats == before
+
+
 @settings(max_examples=15)
 @given(picks=st.lists(st.integers(min_value=0, max_value=2999), min_size=1,
                       max_size=60))
