@@ -58,7 +58,7 @@ from repro.launch.roofline import (
     model_flops,
     roofline_from_compiled,
 )
-from repro.launch.sharding import batch_shardings, shardings_from_specs
+from repro.launch.sharding import abstract, batch_shardings, shardings_from_specs
 from repro.models.registry import build_model
 from repro.train.loop import make_train_step
 from repro.train.optimizer import AdamWConfig
@@ -66,28 +66,11 @@ from repro.train.optimizer import AdamWConfig
 
 def abstract_init(api):
     """(param ShapeDtypeStructs, logical specs) with zero allocation."""
-    box = {}
-
-    def trace_me(key):
-        params, specs = api.init(key)
-        box["specs"] = specs
-        return params
-
-    params_struct = jax.eval_shape(
-        trace_me, jax.ShapeDtypeStruct((2,), jnp.uint32)
-    )
-    return params_struct, box["specs"]
+    return abstract(api.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
 
 
 def abstract_cache(api, batch: int, max_len: int):
-    box = {}
-
-    def trace_me():
-        cache, spec = api.cache_init(batch, max_len)
-        box["spec"] = spec
-        return cache
-
-    return jax.eval_shape(trace_me), box["spec"]
+    return abstract(lambda: api.cache_init(batch, max_len))
 
 
 def param_stats(params_struct, specs) -> dict:

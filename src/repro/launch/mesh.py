@@ -12,7 +12,7 @@ ones.  Activate a mesh with ``jax.set_mesh(mesh)``.
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import AxisType
@@ -27,12 +27,14 @@ def make_production_mesh(*, multi_pod: bool = False):
     return make_mesh(shape, axes)
 
 
-def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]):
+def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],
+              devices: Optional[Sequence] = None):
     """Arbitrary mesh (elastic re-carve after node loss, smoke meshes…)
-    with Auto axes."""
+    with Auto axes, over ``devices`` (default: all of them)."""
     if len(shape) != len(axes):
         raise ValueError(f"shape {shape} / axes {axes} mismatch")
-    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def mesh_from_str(spec: str):

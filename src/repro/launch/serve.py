@@ -16,8 +16,8 @@ by ``--block-size`` KV blocks, and the report adds the TTFT/inter-token
 SLO percentiles plus the prefix-cache hit counters.  Prompts sharing a
 block-aligned prefix share its KV via the prefix cache (on by default;
 ``--no-prefix-cache`` disables sharing — outputs are byte-identical
-either way).  Continuous mode is single-device (``--mesh`` other than
-1x1 is rejected rather than silently ignored).
+either way).  With ``--mesh`` it serves sharded as the static engine
+does: weights at their logical shardings, the KV pool split by KV head.
 """
 
 from __future__ import annotations
@@ -67,9 +67,8 @@ def main():
     api = build_model(cfg)
     params, specs = api.init(jax.random.PRNGKey(0))
 
+    mesh = mesh_from_str(args.mesh)
     if args.continuous:
-        if args.mesh != "1x1":
-            raise SystemExit("--continuous serves single-device; drop --mesh")
         if not api.supports_paged:
             raise SystemExit(
                 f"--arch {args.arch} has no paged-KV decode path "
@@ -90,11 +89,13 @@ def main():
             ServeConfig(max_new_tokens=args.max_new_tokens,
                         max_len=spec.max_len),
             prefix_cache=args.prefix_cache,
+            mesh=mesh, param_specs=specs,
         )
         print(f"serving {len(args.prompts)} prompts on {args.arch} "
               f"({'full' if args.full_config else 'smoke'} config, "
               f"continuous: {args.max_slots} slots x "
-              f"{spec.max_blocks_per_seq} blocks of {args.block_size})…")
+              f"{spec.max_blocks_per_seq} blocks of {args.block_size}, "
+              f"mesh {args.mesh})…")
         for i, r in enumerate(eng.generate(args.prompts)):
             print(f"[{i}] prefill {r.prefill_s*1e3:.0f} ms, "
                   f"{r.tokens_per_s:.1f} tok/s → {r.text[:60]!r}")
@@ -116,7 +117,6 @@ def main():
         eng.close()
         return
 
-    mesh = mesh_from_str(args.mesh)
     eng = Engine(
         cfg, params,
         ServeConfig(max_new_tokens=args.max_new_tokens, max_len=args.max_len),

@@ -2,7 +2,7 @@
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +12,7 @@ from repro.dist.logical import current_rules, divisible_spec
 from repro.launch.mesh import dp_axes
 
 __all__ = [
+    "abstract",
     "shardings_from_specs",
     "batch_shardings",
     "state_shardings",
@@ -19,6 +20,18 @@ __all__ = [
 ]
 
 PyTree = Any
+
+
+def abstract(init, *args) -> Tuple[PyTree, PyTree]:
+    """``init(*args) -> (arrays, logical specs)`` traced, not run:
+    ``(ShapeDtypeStructs, specs)`` with nothing allocated."""
+    box = {}
+
+    def trace_me(*a):
+        arrays, box["specs"] = init(*a)
+        return arrays
+
+    return jax.eval_shape(trace_me, *args), box["specs"]
 
 
 def _is_spec(x) -> bool:

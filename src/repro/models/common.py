@@ -25,6 +25,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.device import on_tpu
@@ -542,8 +543,47 @@ def embed_init(key, cfg: ModelConfig):
     return params, specs
 
 
+def _vocab_axis(mesh, vocab: int) -> Optional[str]:
+    """The mesh axis the vocabulary is split over, on a tensor-parallel
+    mesh (every other axis of size 1) that divides it; else None."""
+    if mesh.empty:
+        return None
+    axis = current_rules().mesh_axes("vocab", mesh.axis_names)
+    if not isinstance(axis, str) or mesh.shape[axis] == 1:
+        return None
+    if vocab % mesh.shape[axis] or any(
+            n > 1 for a, n in mesh.shape.items() if a != axis):
+        return None
+    return axis
+
+
 def embed_apply(params, cfg: ModelConfig, tokens: jax.Array) -> jax.Array:
     cdt = compute_dtype(cfg)
+    mesh = jax.sharding.get_abstract_mesh()
+    axis = _vocab_axis(mesh, cfg.vocab_size)
+    if axis is not None:
+        # Tensor parallel: each device looks the tokens up in its own
+        # vocabulary rows (zeros for tokens it does not hold), and one
+        # all-reduce of the (B, S, D) rows assembles them — the table never
+        # moves.  Exactly one device contributes each row, so the sum is
+        # the row itself.
+        table_spec = divisible_spec(
+            current_rules().spec(("vocab", "embed"), mesh),
+            params["table"].shape, mesh,
+        )
+
+        def lookup(table, ids):
+            n = table.shape[0]
+            local = ids - lax.axis_index(axis) * n
+            hit = (local >= 0) & (local < n)
+            rows = table[jnp.where(hit, local, 0)].astype(cdt)
+            return lax.psum(jnp.where(hit[..., None], rows, 0), axis)
+
+        x = jax.shard_map(
+            lookup, mesh=mesh, in_specs=(table_spec, P()), out_specs=P(),
+            check_vma=False,
+        )(params["table"], tokens)
+        return constrain(x, "batch", "seq", None)
     # Relayout the table for the lookup: vocab-replicated, d_model sharded
     # over the FSDP axes.  Gathering straight from the (vocab→model,
     # d→fsdp) training layout makes SPMD "involuntarily fully rematerialize"

@@ -14,6 +14,7 @@ KV cache build), ``lm_decode_step`` (one-token serve), ``lm_cache_init``.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
@@ -23,7 +24,7 @@ from jax import lax
 
 from repro import flags
 from repro.configs.base import ModelConfig
-from repro.dist.logical import constrain
+from repro.dist.logical import axis_rules, constrain
 from repro.models import moe as moe_mod
 from repro.models.common import (
     attend,
@@ -241,6 +242,23 @@ def lm_cache_init(cfg: ModelConfig, batch: int, max_len: int):
     return cache, spec
 
 
+def _whole_sequence(fn):
+    """Serving prefill keeps the residual whole along the sequence (no
+    sequence parallelism): a layer's tensor-parallel output is combined by
+    one all-reduce, q/k/v and the cache come out split by head as the
+    weights and the paged pool are, and a prompt length the model axis
+    does not split evenly needs no halo exchange.  (With the residual split
+    by sequence, the TPU compiler's partitioner overflowed its stack on a
+    windowed matmul of a 32-layer prefill over four chips.)"""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        with axis_rules({"seq_sp": None}):
+            return fn(*args, **kwargs)
+
+    return wrapped
+
+
+@_whole_sequence
 def lm_prefill(
     params,
     cfg: ModelConfig,
@@ -488,6 +506,7 @@ def lm_paged_prefill_write(
     return jax.tree_util.tree_map(write, cache, prefill_cache)
 
 
+@_whole_sequence
 def lm_prefill_suffix(
     params,
     cfg: ModelConfig,

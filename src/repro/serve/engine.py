@@ -32,7 +32,8 @@ from repro.data.tokenizer import ByteTokenizer
 from repro.launch.sharding import batch_shardings, replicated, shardings_from_specs
 from repro.models.registry import ModelApi, build_model
 
-__all__ = ["ServeConfig", "Engine", "GenerationResult"]
+__all__ = ["ServeConfig", "Engine", "GenerationResult", "mesh_context",
+           "place_params"]
 
 
 @dataclasses.dataclass
@@ -64,6 +65,24 @@ class GenerationResult:
         return self.steps / self.decode_s if self.decode_s > 0 else float("inf")
 
 
+def mesh_context(mesh):
+    """``jax.set_mesh(mesh)`` (activates the models' sharding rules), or a
+    no-op without a mesh."""
+    if mesh is None:
+        return contextlib.nullcontext()
+    return jax.set_mesh(mesh)
+
+
+def place_params(params, mesh, param_specs=None):
+    """The weights at their logical shardings on ``mesh`` (replicated
+    without ``param_specs``); without a mesh they stay where they are."""
+    if mesh is None:
+        return params
+    sh = (shardings_from_specs(mesh, param_specs, params)
+          if param_specs is not None else replicated(mesh))
+    return jax.device_put(params, sh)
+
+
 class Engine:
     def __init__(
         self,
@@ -78,14 +97,7 @@ class Engine:
         self.scfg = scfg
         self.mesh = mesh
         self.tok = ByteTokenizer()
-        if mesh is not None:
-            sh = (
-                shardings_from_specs(mesh, param_specs, params)
-                if param_specs is not None
-                else replicated(mesh)
-            )
-            params = jax.device_put(params, sh)
-        self.params = params
+        self.params = place_params(params, mesh, param_specs)
         self._prefill = jax.jit(
             lambda p, batch: self.api.prefill(p, batch, max_len=scfg.max_len)
         )
@@ -114,12 +126,6 @@ class Engine:
             return cur, pos + 1, cache, out_buf, n_emit, done, key
 
         self._fused_step = jax.jit(fused, donate_argnums=(3, 4, 5, 6))
-
-    def _mesh_ctx(self):
-        """The mesh context (activates the sharding rules) or a no-op."""
-        if self.mesh is None:
-            return contextlib.nullcontext()
-        return jax.set_mesh(self.mesh)
 
     def _shard_batch(self, extras: Dict[str, Any]) -> Dict[str, Any]:
         """Spread the request batch over the mesh's data-parallel axes."""
@@ -163,7 +169,7 @@ class Engine:
 
         extras = self._shard_batch(extras)
         t0 = time.perf_counter()
-        with self._mesh_ctx():
+        with mesh_context(self.mesh):
             logits, cache = self._prefill(self.params, extras)
         logits.block_until_ready()
         prefill_s = time.perf_counter() - t0
@@ -186,7 +192,7 @@ class Engine:
         for step in range(self.scfg.max_new_tokens):
             if step % sync_every == 0 and step and bool(jnp.all(done)):
                 break
-            with self._mesh_ctx():
+            with mesh_context(self.mesh):
                 cur, pos, cache, out_buf, n_emit, done, key = (
                     self._fused_step(
                         self.params, cur, pos, cache, out_buf, n_emit,

@@ -45,6 +45,16 @@ sequence stops after emitting EOS or ``max_new_tokens`` tokens.  On a
 uniform batch the two engines produce identical ``token_ids``
 (``tests/test_continuous_batching.py`` pins this bitwise).
 
+Sharded serving: pass ``mesh`` (and the ``param_specs`` returned by
+``api.init``), as for the static engine.  The weights go to their logical
+shardings, the pool is made already split by KV head (its
+``paged_cache_init`` specs) and every program that returns it pins it to
+that layout, so the donated pool stays in place from step to step; each
+step's host inputs are put on the mesh replicated (span ``engine.place``,
+``ContinuousStats.place_s``), and the leader runs admission, prefill, the
+paged write and decode inside the mesh context.  Without a mesh every
+program is the one traced before meshes existed.
+
 Per-request SLO accounting records time-to-first-token (submit → prefill
 argmax) and inter-token latency (consecutive decode materializations) in
 bounded windows; ``slo_ms()`` reports p50/p99 of both.  The time each
@@ -53,7 +63,8 @@ admitted request waited in the queue adds to
 spans (:mod:`repro.trace`): ``engine.admit`` around each admission, with
 ``engine.prefill`` (dispatch through the first token on the host) inside
 it, and per decode step ``engine.step`` (input upload, dispatch, the
-host sync) then ``engine.emit`` (emit and evict).
+host sync; on a mesh ``engine.place`` inside it, the upload) then
+``engine.emit`` (emit and evict).
 """
 
 from __future__ import annotations
@@ -72,7 +83,10 @@ import numpy as np
 from repro.configs.base import ModelConfig
 from repro.data.tokenizer import ByteTokenizer
 from repro.models.registry import build_model
-from repro.serve.engine import GenerationResult, ServeConfig
+from repro.launch.sharding import abstract, replicated, shardings_from_specs
+from repro.serve.engine import (
+    GenerationResult, ServeConfig, mesh_context, place_params,
+)
 from repro.serve.kvcache import (
     BlockManager, PagedCacheSpec, PrefixIndex, blocks_for,
 )
@@ -106,6 +120,7 @@ class ContinuousStats:
     prefix_misses: int = 0      # prefix-eligible admissions with no match
     prefill_tokens_saved: int = 0  # prompt tokens whose prefill was skipped
     queue_wait_s: float = 0.0   # sum over admissions of (admission - submit)
+    place_s: float = 0.0        # putting step inputs on the mesh (0 without one)
 
     @property
     def tokens_per_step(self) -> float:
@@ -170,6 +185,8 @@ class ContinuousEngine:
         spec: PagedCacheSpec,
         scfg: ServeConfig = ServeConfig(),
         prefix_cache: bool = True,
+        mesh=None,
+        param_specs=None,
     ):
         self.cfg = cfg
         self.api = build_model(cfg)
@@ -181,13 +198,31 @@ class ContinuousEngine:
             )
         self.spec = spec
         self.scfg = scfg
-        self.params = params
+        self.mesh = mesh
+        self.params = place_params(params, mesh, param_specs)
         self.tok = ByteTokenizer()
         self.stats = ContinuousStats()
         self._offset = cfg.n_img_tokens or 0
 
         self._mgr = BlockManager(spec)
-        self._cache, _ = self.api.paged_cache_init(spec.n_blocks, spec.block_size)
+        if mesh is None:
+            self._cache, _ = self.api.paged_cache_init(
+                spec.n_blocks, spec.block_size)
+            self._put = jnp.asarray
+            pin = lambda c: c  # noqa: E731
+        else:
+            def pool():
+                return self.api.paged_cache_init(spec.n_blocks, spec.block_size)
+
+            shapes, specs = abstract(pool)
+            cache_sh = shardings_from_specs(mesh, specs, shapes)
+            # made in place, split: the whole pool never sits on one device
+            self._cache = jax.jit(lambda: pool()[0], out_shardings=cache_sh)()
+            rep_sh = replicated(mesh)
+            self._put = lambda x: jax.device_put(x, rep_sh)  # noqa: E731
+
+            def pin(c):
+                return jax.lax.with_sharding_constraint(c, cache_sh)
 
         # Prefix sharing needs bitwise-reproducible prefill: MoE capacity
         # routing depends on the prefill batch shape (suffix vs full give
@@ -226,13 +261,13 @@ class ContinuousEngine:
                 logits, cache = self.api.decode_step_paged(
                     p, cur, pos, tables, cache, bs
                 )
-                return jnp.argmax(logits, -1).astype(jnp.int32), cache
+                return jnp.argmax(logits, -1).astype(jnp.int32), pin(cache)
         else:
             def step(p, cur, pos, tables, cache, seeds, idx):
                 logits, cache = self.api.decode_step_paged(
                     p, cur, pos, tables, cache, bs
                 )
-                return sample_rows(logits, seeds, idx), cache
+                return sample_rows(logits, seeds, idx), pin(cache)
 
         self._step = jax.jit(step, donate_argnums=(4,))
         self._sample_first = jax.jit(
@@ -244,16 +279,21 @@ class ContinuousEngine:
             lambda p, b: self.api.prefill(p, b, max_len=spec.max_len)
         )
         self._write = jax.jit(
-            lambda c, pc, row: self.api.paged_prefill_write(c, pc, row, bs),
+            lambda c, pc, row: pin(self.api.paged_prefill_write(c, pc, row, bs)),
             donate_argnums=(0,),
         )
         # suffix prefill retraces per (suffix bucket, start) pair — both
         # multiples of block_size and bounded by the table width M, so the
         # trace count is bounded by M² for the engine's lifetime
-        self._prefill_suffix = jax.jit(
-            lambda p, t, start, row, c, lengths: self.api.prefill_suffix(
+        def suffix(p, t, start, row, c, lengths):
+            logits, c = self.api.prefill_suffix(
                 p, t, start, row, c, bs, lengths=lengths
-            ),
+            )
+            return logits, pin(c)
+
+        self._prefill_suffix = jax.jit(
+            lambda p, t, start, row, c, lengths: suffix(
+                p, t, start, row, c, lengths),
             static_argnums=(2,),
             donate_argnums=(4,),
         )
@@ -265,7 +305,7 @@ class ContinuousEngine:
         self._idx = np.zeros((spec.max_slots,), np.int32)
         self._active: Dict[int, _Seq] = {}
         self._free_slots: List[int] = list(range(spec.max_slots - 1, -1, -1))
-        self._tables_dev = jnp.asarray(self._mgr.tables)
+        self._tables_dev = self._put(self._mgr.tables)
         self._tables_dirty = False
 
         self._lock = threading.Lock()      # queue, stop flag, SLO windows
@@ -360,14 +400,15 @@ class ContinuousEngine:
         queued for the next leader.
         """
         try:
-            while True:
-                self._admit()
-                if not self._active:
-                    with self._lock:
-                        if not self._queue or self._stop:
-                            return
-                    continue  # backpressure cleared by an eviction race
-                self._decode_once()
+            with mesh_context(self.mesh):
+                while True:
+                    self._admit()
+                    if not self._active:
+                        with self._lock:
+                            if not self._queue or self._stop:
+                                return
+                        continue  # backpressure cleared by an eviction race
+                    self._decode_once()
         except BaseException as e:  # noqa: BLE001 — delivered first
             for slot, seq in list(self._active.items()):
                 if not seq.future.done():
@@ -479,10 +520,10 @@ class ContinuousEngine:
                 assert admitted, "can_admit passed but admit failed (leader is sole allocator)"
                 suf = np.full((1, bucket - start), self.tok.pad_id, np.int32)
                 suf[0, : L - start] = prompt[start:]
-                row = jnp.asarray(self._mgr.tables[slot])
+                row = self._put(self._mgr.tables[slot])
                 logits, self._cache = self._prefill_suffix(
-                    self.params, jnp.asarray(suf), start, row, self._cache,
-                    jnp.asarray([L - start], jnp.int32),
+                    self.params, self._put(suf), start, row, self._cache,
+                    self._put(np.asarray([L - start], np.int32)),
                 )
                 dense = None
                 self.stats.prefix_hits += 1
@@ -493,13 +534,13 @@ class ContinuousEngine:
                 toks = np.full((1, bucket), self.tok.pad_id, np.int32)
                 toks[0, :L] = prompt
                 batch: Dict[str, Any] = {
-                    "tokens": jnp.asarray(toks),
-                    "lengths": jnp.asarray([L], jnp.int32),
+                    "tokens": self._put(toks),
+                    "lengths": self._put(np.asarray([L], np.int32)),
                 }
                 if self.cfg.family == "vlm":
-                    batch["patch_embeds"] = jnp.zeros(
-                        (1, self.cfg.n_img_tokens, self.cfg.d_model), jnp.float32
-                    )
+                    batch["patch_embeds"] = self._put(np.zeros(
+                        (1, self.cfg.n_img_tokens, self.cfg.d_model), np.float32
+                    ))
                 logits, dense = self._prefill(self.params, batch)
             first = self._first_token(logits, req.seed)
             now = time.perf_counter()
@@ -532,7 +573,7 @@ class ContinuousEngine:
             slot = self._free_slots.pop()
             admitted = self._mgr.admit(slot, total)
             assert admitted, "can_admit passed but admit failed (leader is sole allocator)"
-            row = jnp.asarray(self._mgr.tables[slot])
+            row = self._put(self._mgr.tables[slot])
             self._cache = self._write(self._cache, dense, row)
         if self._index is not None:
             # publish every full-block prefix: decode writes land in the
@@ -555,7 +596,7 @@ class ContinuousEngine:
             return int(jnp.argmax(logits[0]))
         return int(
             self._sample_first(
-                logits[0], jnp.asarray(seed & 0xFFFFFFFF, jnp.uint32)
+                logits[0], self._put(np.uint32(seed & 0xFFFFFFFF))
             )
         )
 
@@ -563,19 +604,14 @@ class ContinuousEngine:
         """One batched paged decode step + host-side emit/evict."""
         step = self.stats.steps + 1
         with span("engine.step", step=step, active=len(self._active)):
-            if self._tables_dirty:
-                self._tables_dev = jnp.asarray(self._mgr.tables)
-                self._tables_dirty = False
-            args = (
-                self.params,
-                jnp.asarray(self._cur),
-                jnp.asarray(self._pos),
-                self._tables_dev,
-                self._cache,
-            )
-            if not self.scfg.greedy:
-                args = args + (jnp.asarray(self._seeds), jnp.asarray(self._idx))
-            nxt, self._cache = self._step(*args)
+            if self.mesh is None:
+                inputs = self._step_inputs()
+            else:
+                t0 = time.perf_counter()
+                with span("engine.place", step=step):
+                    inputs = self._step_inputs()
+                self.stats.place_s += time.perf_counter() - t0
+            nxt, self._cache = self._step(*inputs)
             nxt = np.asarray(nxt)  # the one host sync per step: (S,) int32
             now = time.perf_counter()
         self.stats.steps = step
@@ -595,6 +631,18 @@ class ContinuousEngine:
                     self._cur[slot, 0] = tok
                     self._pos[slot] += 1
                     self._idx[slot] = len(seq.tokens)
+
+    def _step_inputs(self) -> tuple:
+        """The decode step's arguments, host state uploaded (the block
+        tables only after a change)."""
+        if self._tables_dirty:
+            self._tables_dev = self._put(self._mgr.tables)
+            self._tables_dirty = False
+        args = (self.params, self._put(self._cur), self._put(self._pos),
+                self._tables_dev, self._cache)
+        if not self.scfg.greedy:
+            args = args + (self._put(self._seeds), self._put(self._idx))
+        return args
 
     def _evict(self, slot: int, seq: _Seq, now: float) -> None:
         self._mgr.release(slot)

@@ -24,11 +24,10 @@ import pytest
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(
@@ -42,10 +41,17 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
     finally:
         jax.config.update("jax_enable_compilation_cache", prev)
         compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compile_for_chip(fn, *shapes, sharding):
@@ -114,3 +120,55 @@ def test_flash_attention_compiles(one_chip, sq, skv):
         ((1, 4, skv, 128), jnp.bfloat16),
         sharding=one_chip,
     )
+
+
+def test_tp_decode_step_compiles_for_four_chips(topo):
+    """Yi-6B whole, the continuous engine's paged decode step on a 1x4
+    mesh of the described chips: it fits a chip, and the only all-gather
+    in it is the argmax's (a few values a slot) — no weight, embedding
+    table or KV pool moves between chips; the per-layer combines are
+    all-reduces of the (64, 1, 4096) activations."""
+    import re
+
+    import numpy as np
+    from jax.sharding import AxisType, Mesh
+
+    from repro.configs.base import ModelConfig
+    from repro.launch.sharding import abstract, shardings_from_specs
+    from repro.models.registry import build_model
+
+    cfg = ModelConfig(name="yi-6b-full", family="dense", n_layers=32,
+                      d_model=4096, n_heads=32, n_kv_heads=4, head_dim=128,
+                      d_ff=11008, vocab_size=64000, rope_theta=5e6,
+                      norm_eps=1e-5)
+    api = build_model(cfg)
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    slots, m, bs = 64, 128, 16
+
+    def placed(shapes, specs):
+        sh = shardings_from_specs(mesh, specs, shapes)
+        return jax.tree_util.tree_map(
+            lambda s, h: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=h),
+            shapes, sh)
+
+    params = placed(*abstract(api.init, jax.ShapeDtypeStruct((2,), jnp.uint32)))
+    pool = placed(*abstract(lambda: api.paged_cache_init(slots * m + 1, bs)))
+    rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
+
+    def step(p, cur, pos, tables, cache):
+        logits, cache = api.decode_step_paged(p, cur, pos, tables, cache, bs)
+        return jnp.argmax(logits, -1).astype(jnp.int32), cache
+
+    ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)  # noqa: E731
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(step, donate_argnums=(4,)).lower(
+            params, ints(slots, 1), ints(slots), ints(slots, m), pool).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+    hlo = compiled.as_text()
+    gathered = re.findall(r"= (\w+)\[([\d,]*)\][^ ]* all-gather(?:-start)?\(", hlo)
+    assert all(int(np.prod([int(d) for d in dims.split(",")])) <= 4 * slots
+               for _, dims in gathered), gathered
+    reduced = re.findall(r"= \w+\[([\d,]*)\][^ ]* all-reduce(?:-start)?\(", hlo)
+    assert f"{slots},1,4096" in reduced, reduced
