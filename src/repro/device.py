@@ -2,7 +2,7 @@
 
 Every choice between a Pallas kernel and its host or XLA counterpart —
 the store's digest probe, similarity scoring, the extraction verify, and
-the five kernels' own entry points — goes through :func:`on_tpu`.  It
+the kernels' own entry points — goes through :func:`on_tpu`.  It
 asks JAX for its default backend (importing JAX if nothing has yet), so
 the answer does not depend on what the caller happened to import first,
 and an error in JAX's start-up surfaces instead of quietly selecting the

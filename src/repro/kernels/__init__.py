@@ -15,6 +15,8 @@ a described v5e chip.
 * ``tanimoto``        — batched Tanimoto top-k over packed fingerprint
                         bit-planes (the similarity query modality).
 * ``flash_attention`` — causal/sliding-window GQA flash attention.
+* ``paged_decode``    — one-token GQA attention over a paged KV pool,
+                        reading each slot's live blocks where they lie.
 * ``ssd_scan``        — Mamba2 SSD inter-chunk state recurrence.
 """
 
@@ -22,4 +24,5 @@ from .hash_mix.ops import hash_mix, hash_mix_u64
 from .sorted_probe.ops import sorted_probe
 from .tanimoto.ops import tanimoto_topk
 from .flash_attention.ops import flash_attention
+from .paged_decode.ops import paged_decode
 from .ssd_scan.ops import ssd_scan

@@ -31,6 +31,8 @@ from repro.configs.base import ModelConfig
 from repro.device import on_tpu
 from repro.dist.logical import constrain, current_rules, divisible_spec
 from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.paged_decode.ops import paged_decode, tiles_fit
+from repro.kernels.paged_decode.ref import decode_attention, paged_view
 
 __all__ = [
     "Dtypes",
@@ -40,6 +42,7 @@ __all__ = [
     "rope_freqs",
     "apply_rope",
     "attend",
+    "paged_attend",
     "attention_init",
     "attention_apply",
     "attention_decode",
@@ -157,6 +160,72 @@ def attend(
     )(q, k, v)
 
 
+def paged_attend(
+    q: jax.Array,          # (B, H, Dh)
+    k_new: jax.Array,      # (B, Hkv, Dh) the new token's K and V rows
+    v_new: jax.Array,
+    k_pool: jax.Array,     # (L, Hkv, P, Dh) pools of every layer
+    v_pool: jax.Array,
+    tables: jax.Array,     # (B, M) int32 block tables
+    pos: jax.Array,        # (B,) int32 position of the new token
+    layer,                 # int32 scalar: the pools' layer
+    block_size: int,
+    use_pallas: Optional[bool] = None,
+    interpret: bool = False,
+):
+    """Write each slot's new K/V row at ``pos`` into layer ``layer``'s pool,
+    then :func:`paged_decode` over positions ``0..pos`` → (k_pool, v_pool,
+    context (B, H, Dh) f32), laid out on the active mesh.
+
+    The rows are scattered one (Dh,) row per slot and KV head: a scatter
+    whose window is the pool's minor dim leaves the pool in the row-major
+    layout the kernel reads (a window over heads and Dh makes the compiler
+    relayout the whole pool).  As in :func:`attend`, the Pallas kernel runs
+    inside ``shard_map`` under a mesh, and the write with it: query heads
+    over the axis the ``"heads"`` rule names, the pool's KV heads over the
+    one ``"kv_heads"`` names, so each device writes and attends the KV
+    heads it holds, with no collective.  Where the pool is not split like
+    the query heads (the KV heads do not divide that axis) the reference
+    runs instead, partitioned by the compiler: the pool is too large to
+    repeat.
+    """
+    if use_pallas is None:
+        use_pallas = on_tpu() and tiles_fit(
+            q.shape[-1], block_size, k_pool.dtype)
+
+    def write_attend(q, k_new, v_new, k_pool, v_pool, tables, pos, layer,
+                     use_pallas=use_pallas):
+        b, hkv = k_new.shape[:2]
+        rows = (tables[jnp.arange(b), pos // block_size] * block_size
+                + pos % block_size)[:, None]
+        heads = jnp.arange(hkv)[None, :]
+        k_pool = k_pool.at[layer, heads, rows, :].set(k_new.astype(k_pool.dtype))
+        v_pool = v_pool.at[layer, heads, rows, :].set(v_new.astype(v_pool.dtype))
+        ctx = paged_decode(q, k_pool, v_pool, tables, pos + 1, layer,
+                           block_size, use_pallas=use_pallas,
+                           interpret=interpret)
+        return k_pool, v_pool, ctx
+
+    args = (q, k_new, v_new, k_pool, v_pool, tables, pos, layer)
+    mesh = jax.sharding.get_abstract_mesh()
+    if not use_pallas or mesh.empty:
+        return write_attend(*args)
+    rules = current_rules()
+    row_spec = divisible_spec(rules.spec((None, "heads", None), mesh),
+                              q.shape, mesh)
+    pool_spec = divisible_spec(rules.spec((None, "kv_heads", None, None), mesh),
+                               k_pool.shape, mesh)
+    if divisible_spec(row_spec, k_new.shape, mesh) != row_spec or \
+            tuple(pool_spec)[1] != tuple(row_spec)[1]:
+        return write_attend(*args, use_pallas=False)
+    return jax.shard_map(
+        write_attend, mesh=mesh,
+        in_specs=(row_spec, row_spec, row_spec, pool_spec, pool_spec,
+                  P(), P(), P()),
+        out_specs=(pool_spec, pool_spec, row_spec), check_vma=False,
+    )(*args)
+
+
 def attention_init(key, cfg: ModelConfig) -> Tuple[PyTree, PyTree]:
     d, h, hkv = cfg.d_model, cfg.n_heads, cfg.n_kv_heads
     dh = cfg.resolved_head_dim
@@ -240,26 +309,6 @@ def attention_apply(
 
     out = constrain(out, *_flags.residual_axes())
     return checkpoint_name(out, "attn_out")
-
-
-def _gqa_decode_scores(q, k_cache, valid, cdt):
-    """q (B,H,Dh), k_cache (B,Hkv,S,Dh), valid (B,S) -> ctx weights (B,H,S).
-
-    §Perf note: the matmul runs in the cache dtype with f32 accumulation
-    (preferred_element_type) — casting the whole cache to f32 doubled the
-    decode cells' HBM traffic in the baseline dry-run.
-    """
-    b, h, dh = q.shape
-    hkv = k_cache.shape[1]
-    g = h // hkv
-    qg = q.reshape(b, hkv, g, dh).astype(k_cache.dtype)
-    s = jnp.einsum(
-        "bkgd,bksd->bkgs", qg, k_cache, preferred_element_type=jnp.float32
-    )
-    s = s / math.sqrt(dh)
-    s = jnp.where(valid[:, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    return p  # (B, Hkv, G, S)
 
 
 def decode_attention_chunked(
@@ -376,11 +425,7 @@ def attention_decode(
     if _flags.DECODE_CHUNKED:
         ctx = decode_attention_chunked(q, k_cache, v_cache, valid)
     else:
-        p = _gqa_decode_scores(q, k_cache, valid, cdt)  # (B,Hkv,G,S) f32
-        ctx = jnp.einsum(
-            "bkgs,bksd->bkgd", p.astype(v_cache.dtype), v_cache,
-            preferred_element_type=jnp.float32,
-        )
+        ctx = decode_attention(q, k_cache, v_cache, valid)  # (B,Hkv,G,Dh)
     ctx = ctx.reshape(b, h * dh).astype(cdt)
     out = (ctx @ params["wo"].astype(cdt))[:, None, :]  # (B,1,D)
     return out, {"k": k_cache, "v": v_cache}
@@ -401,21 +446,6 @@ def attention_decode(
 # is reserved as a trash block: unallocated table entries point at it, so
 # writes from inactive slots land somewhere harmless and reads from it
 # are always masked by the position-validity mask.
-
-
-def paged_view(pool: jax.Array, tables: jax.Array, block_size: int) -> jax.Array:
-    """Gather per-slot contiguous KV views out of the block pool.
-
-    pool (Hkv, P, Dh), tables (B, M) int32 → (B, Hkv, M*bs, Dh).  The
-    gather is jit-stable: output shape depends only on the static table
-    width, never on how many blocks a slot actually owns.
-    """
-    b, m = tables.shape
-    flat = (
-        tables[:, :, None] * block_size
-        + jnp.arange(block_size, dtype=tables.dtype)[None, None, :]
-    ).reshape(b, m * block_size)
-    return jnp.swapaxes(pool[:, flat], 0, 1)  # (B, Hkv, L, Dh)
 
 
 def paged_write_rows(
@@ -441,58 +471,33 @@ def attention_decode_paged(
     cfg: ModelConfig,
     x: jax.Array,                 # (B, 1, D)
     pos: jax.Array,               # (B,) absolute position of the new token
-    cache: Dict[str, jax.Array],  # {"k","v"}: (Hkv, P, Dh) block pools
+    cache: Dict[str, jax.Array],  # {"k","v"}: (L, Hkv, P, Dh) pools of every layer
     tables: jax.Array,            # (B, M) int32 block tables
     block_size: int,
+    layer,                        # int32 scalar: this layer's pool
     use_rope: bool = True,
 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One-token decode against the paged pool.
+    """One-token decode against the paged pool of layer ``layer``.
 
-    Write-then-gather: the new token's K/V goes to its slot's block at
-    ``pos``, then the slot's blocks are gathered into a contiguous
-    (B, Hkv, L, Dh) view and the math is exactly
-    :func:`attention_decode`'s — same einsums, same masking constant — so
-    greedy decode is byte-identical to the contiguous cache whenever the
-    view length L matches the contiguous slot count (masked rows
-    contribute exact zeros either way).
+    The new token's K/V goes to its slot's block at ``pos`` (a scatter of
+    B rows into the pools of every layer, in place when the caller carries
+    them), then each slot attends its first ``pos + 1`` positions where
+    they lie in the pool (:func:`paged_attend`): the kernel reads only
+    the blocks below that length, and the reference gathers the slot's
+    whole table width.
     """
     cdt = compute_dtype(cfg)
     b, _, d = x.shape
-    h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    h, dh = cfg.n_heads, cfg.resolved_head_dim
     q, k, v = _qkv(params, cfg, x)            # (B,1,H,Dh)/(B,1,Hkv,Dh)
     if use_rope:
         p1 = pos[:, None]
         q = apply_rope(q, p1, cfg.rope_theta)
         k = apply_rope(k, p1, cfg.rope_theta)
-    q = q[:, 0]                                # (B, H, Dh)
-    k_new = jnp.swapaxes(k, 1, 2)[:, :, 0]     # (B, Hkv, Dh)
-    v_new = jnp.swapaxes(v, 1, 2)[:, :, 0]
-
-    flat_w = (
-        tables[jnp.arange(b), pos // block_size] * block_size
-        + pos % block_size
-    )                                          # (B,)
-    k_pool = cache["k"].at[:, flat_w, :].set(
-        jnp.swapaxes(k_new, 0, 1).astype(cache["k"].dtype)
-    )
-    v_pool = cache["v"].at[:, flat_w, :].set(
-        jnp.swapaxes(v_new, 0, 1).astype(cache["v"].dtype)
-    )
-
-    k_cache = paged_view(k_pool, tables, block_size)   # (B, Hkv, L, Dh)
-    v_cache = paged_view(v_pool, tables, block_size)
-    slots = k_cache.shape[2]
-    valid = jnp.arange(slots)[None, :] <= pos[:, None]
-    from repro import flags as _flags
-
-    if _flags.DECODE_CHUNKED:
-        ctx = decode_attention_chunked(q, k_cache, v_cache, valid)
-    else:
-        p = _gqa_decode_scores(q, k_cache, valid, cdt)  # (B,Hkv,G,S) f32
-        ctx = jnp.einsum(
-            "bkgs,bksd->bkgd", p.astype(v_cache.dtype), v_cache,
-            preferred_element_type=jnp.float32,
-        )
+    k_pool, v_pool, ctx = paged_attend(
+        q[:, 0], k[:, 0], v[:, 0], cache["k"], cache["v"], tables, pos,
+        layer, block_size,
+    )                                          # ctx (B, H, Dh) f32
     ctx = ctx.reshape(b, h * dh).astype(cdt)
     out = (ctx @ params["wo"].astype(cdt))[:, None, :]  # (B,1,D)
     return out, {"k": k_pool, "v": v_pool}

@@ -39,6 +39,7 @@ from repro.models.common import (
     last_token_logits,
     mlp_apply,
     mlp_init,
+    paged_view,
     paged_write_rows,
     rmsnorm,
     rmsnorm_init,
@@ -437,15 +438,20 @@ def lm_decode_step_paged(
     cache: PyTree,           # lm_paged_cache_init layout
     block_size: int,
 ) -> Tuple[jax.Array, PyTree]:
-    """One-token decode against the shared block pool.  → (logits, cache)."""
+    """One-token decode against the shared block pool.  → (logits, cache).
+
+    The pools ride the layer scan as its carry, whole: each layer scatters
+    its B new rows into them in place and attends layer ``l``'s rows
+    where they lie, so no layer slices out or stacks back a pool-sized
+    copy."""
     _require_no_windows(cfg)
     x = embed_apply(params["embed"], cfg, token)
-    _, per = _n_scan(cfg)
+    n_steps, per = _n_scan(cfg)
 
-    def sub_decode(p, x, kv):
+    def sub_decode(p, x, kv, layer):
         h = rmsnorm(x, p["ln1"], cfg.norm_eps)
         attn, kv = attention_decode_paged(
-            p["attn"], cfg, h, pos, kv, tables, block_size
+            p["attn"], cfg, h, pos, kv, tables, block_size, layer
         )
         x = x + attn
         h = rmsnorm(x, p["ln2"], cfg.norm_eps)
@@ -455,21 +461,21 @@ def lm_decode_step_paged(
             y = mlp_apply(p["mlp"], cfg, h)
         return x + y, kv
 
-    def body(x, xs):
-        blk, kvs = xs
-        new_kvs = {}
+    def body(carry, xs):
+        x, kvs = carry
+        blk, layer = xs
+        kvs = dict(kvs)
         if per == 1:
-            x, kv = sub_decode(blk, x, kvs["pos0"])
-            new_kvs["pos0"] = kv
+            x, kvs["pos0"] = sub_decode(blk, x, kvs["pos0"], layer)
         else:
             for i in range(per):
                 sub = jax.tree_util.tree_map(lambda v: v[i], blk)
-                x, kv = sub_decode(sub, x, kvs[f"pos{i}"])
-                new_kvs[f"pos{i}"] = kv
-        return x, new_kvs
+                x, kvs[f"pos{i}"] = sub_decode(sub, x, kvs[f"pos{i}"], layer)
+        return (x, kvs), None
 
-    x, new_cache = lax.scan(
-        body, x, (params["blocks"], cache), unroll=flags.scan_unroll()
+    (x, new_cache), _ = lax.scan(
+        body, (x, cache), (params["blocks"], jnp.arange(n_steps)),
+        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]
@@ -535,8 +541,6 @@ def lm_prefill_suffix(
     Asserted by tests, and the basis of the engine's prefix-on vs
     prefix-off byte parity.
     """
-    from repro.models.common import paged_view
-
     _require_no_windows(cfg)
     s = tokens.shape[1]
     if start % block_size != 0:

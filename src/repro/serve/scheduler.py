@@ -121,6 +121,11 @@ class ContinuousStats:
     prefill_tokens_saved: int = 0  # prompt tokens whose prefill was skipped
     queue_wait_s: float = 0.0   # sum over admissions of (admission - submit)
     place_s: float = 0.0        # putting step inputs on the mesh (0 without one)
+    # per layer, summed over decode steps and active slots: the blocks
+    # below each slot's length, which paged decode attention reads, and
+    # the whole table width, which a gather of each slot's view reads
+    kv_blocks_read: int = 0
+    kv_blocks_viewed: int = 0
 
     @property
     def tokens_per_step(self) -> float:
@@ -603,6 +608,10 @@ class ContinuousEngine:
     def _decode_once(self) -> None:
         """One batched paged decode step + host-side emit/evict."""
         step = self.stats.steps + 1
+        live = self._pos[list(self._active)] + 1
+        self.stats.kv_blocks_read += int(
+            (-(-live // self.spec.block_size)).sum())
+        self.stats.kv_blocks_viewed += len(live) * self.spec.max_blocks_per_seq
         with span("engine.step", step=step, active=len(self._active)):
             if self.mesh is None:
                 inputs = self._step_inputs()

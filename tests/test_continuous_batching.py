@@ -205,6 +205,54 @@ def test_reused_blocks_decode_identically_to_fresh(tiny):
     fresh.close()
 
 
+# the chat mix in small: ragged prompts and budgets through 3 slots, so
+# slots are admitted and evicted while others decode (≥ 64 steps)
+CHURN = [("InChI=1S/C4H10/c1-3", 30), ("ab", 12), ("C1=CC=CC=C1O", 25),
+         ("xylene", 18), ("InChI=1S/C8H9NO2/c1-6", 30), ("C6H6", 9),
+         ("smiles:CC(=O)O", 22), ("InChI=1S/H2O/h1H2", 28), ("q", 16),
+         ("ethanol", 24)]
+
+
+def _serve_churn(cfg, params):
+    eng = ContinuousEngine(cfg, params, _spec(),
+                           ServeConfig(max_new_tokens=30, max_len=MAX_LEN))
+    futs = [eng.submit(t, b, lead=False) for t, b in CHURN]
+    eng._maybe_lead()
+    res = [f.result(timeout=600) for f in futs]
+    stats = eng.stats
+    eng.close()
+    return res, stats
+
+
+def test_paged_decode_kernel_serves_the_reference_tokens(tiny, monkeypatch):
+    """The Pallas paged decode kernel (interpreted) in the engine serves
+    the same greedy tokens as the gather-then-attend reference; the block
+    counters match a count by hand from each request's positions.  In
+    float32: in bfloat16 the two round ``p·v`` differently (the kernel's
+    probabilities are cast before they are normalised), and random
+    weights' near-ties then flip a greedy token now and then."""
+    import functools
+
+    import repro.models.common as common
+
+    cfg = _tiny_cfg(dtype="float32")
+    params, _ = build_model(cfg).init(jax.random.PRNGKey(0))
+    want, ref_stats = _serve_churn(cfg, params)
+    monkeypatch.setattr(common, "paged_attend", functools.partial(
+        common.paged_attend, use_pallas=True, interpret=True))
+    got, stats = _serve_churn(cfg, params)
+    assert stats.steps >= 64
+    assert [r.token_ids for r in got] == [r.token_ids for r in want]
+    # a request fed into s decode steps attends positions 0..L+j at step j
+    m = MAX_LEN // BS
+    read = sum(blocks_for(r.prompt_len + j + 1, BS)
+               for r in got for j in range(r.steps))
+    viewed = sum(r.steps for r in got) * m
+    assert (stats.kv_blocks_read, stats.kv_blocks_viewed) == (read, viewed)
+    assert (ref_stats.kv_blocks_read, ref_stats.kv_blocks_viewed) == (read, viewed)
+    assert 0 < stats.kv_blocks_read < stats.kv_blocks_viewed
+
+
 # ---------------------------------------------------------------------------
 # continuous engine vs static engine
 # ---------------------------------------------------------------------------

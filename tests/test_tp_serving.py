@@ -137,6 +137,48 @@ TP_PROG = textwrap.dedent(
     out["embed_gathers"] = len(re.findall(r" all-gather(?:-start)?\\(", hlo))
     out["embed_reduces"] = len(re.findall(r" all-reduce(?:-start)?\\(", hlo))
     out["place_s"] = eng.counters()["place_s"]
+
+    # the paged decode kernel, interpreted, per device inside shard_map,
+    # against the reference path through a churn of admissions and
+    # evictions (float32: see test_continuous_batching)
+    import functools
+    import repro.models.common as common
+
+    cfg32 = dataclasses.replace(cfg, dtype="float32")
+    churn = [("InChI=1S/C4H10/c1-3-4-2", 40), ("ab", 24), ("C1=CC=CC=C1O", 56),
+             ("xylene", 30), ("InChI=1S/C8H9NO2/c1-6(10)9", 48), ("C6H6", 20),
+             ("smiles:CC(=O)O", 44), ("InChI=1S/H2O/h1H2", 36), ("q", 28),
+             ("ethanol", 40), ("C2H5OH", 33)]
+
+    def serve():
+        e = ContinuousEngine(cfg32, params, spec, ServeConfig(max_len=128),
+                             mesh=mesh, param_specs=specs)
+        futs = [e.submit(t, b, lead=False) for t, b in churn]
+        e._maybe_lead()
+        res = [f.result(timeout=600).token_ids for f in futs]
+        return e, res
+
+    ref_eng, want = serve()
+    pools = []
+    kernel = common.paged_decode
+
+    def recorded(q, k_pool, *a, **kw):
+        pools.append(list(k_pool.shape))
+        return kernel(q, k_pool, *a, **kw)
+
+    common.paged_decode = recorded
+    common.paged_attend = functools.partial(
+        common.paged_attend, use_pallas=True, interpret=True)
+    ker_eng, got = serve()
+    with mesh_context(mesh):
+        hlo = ker_eng._step.lower(*ker_eng._step_inputs()).compile().as_text()
+    out["kernel"] = {
+        "same_tokens": got == want, "steps": ker_eng.stats.steps,
+        "pools": pools,
+        "blocks": [ker_eng.stats.kv_blocks_read, ker_eng.stats.kv_blocks_viewed],
+        "ref_blocks": [ref_eng.stats.kv_blocks_read, ref_eng.stats.kv_blocks_viewed],
+        "gathers": re.findall(r"= \\w+\\[([\\d,]*)\\][^ ]* all-gather(?:-start)?\\(", hlo),
+    }
     print("RESULT:" + json.dumps(out))
     """
 )
@@ -201,6 +243,22 @@ def test_tp_embedding_is_looked_up_where_the_vocabulary_lives(tp_run):
 
 def test_tp_step_inputs_are_placed_and_timed(tp_run):
     assert tp_run["place_s"] > 0
+
+
+def test_tp_paged_decode_kernel_serves_the_reference_tokens(tp_run):
+    """On the 1x4 mesh the interpreted paged decode kernel runs once per
+    layer on each device's own KV head, serves the reference path's
+    greedy tokens through admissions and evictions (>= 64 steps), and the
+    step gathers nothing but the argmax's values."""
+    k = tp_run["kernel"]
+    assert k["same_tokens"] and k["steps"] >= 64
+    cfg = tiny_tp_cfg()
+    assert k["pools"] and all(p[:2] == [cfg.n_layers, 1] for p in k["pools"])
+    assert k["blocks"] == k["ref_blocks"]
+    assert 0 < k["blocks"][0] < k["blocks"][1]
+    # the argmax's values: a few a slot on each of the 4 devices
+    assert all(np.prod([int(d) for d in dims.split(",")]) <= 4 * 4
+               for dims in k["gathers"]), k["gathers"]
 
 
 # ---------------------------------------------------------------------------
