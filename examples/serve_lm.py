@@ -2,8 +2,7 @@
 
 Loads (or quickly trains) a small LM on the indexed corpus, then serves a
 batch of molecular-id prompts through the Engine — prefill once, decode
-with per-sequence positions, EOS stopping.  The decode inner loop is the
-same ``serve_step`` the multi-pod dry-run lowers at 32k/500k context.
+with per-sequence positions, EOS stopping.
 
     PYTHONPATH=src python examples/serve_lm.py
 """

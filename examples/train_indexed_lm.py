@@ -7,7 +7,7 @@ Training runs with catalog checkpoints and demonstrates restart.
 
 Defaults are sized for the 1-core CPU container (a ~3M-param model, 80
 steps).  ``--preset 100m --steps 300`` is the full-size configuration for
-real hardware; the dry-run proves the same code lowers at 72B+.
+real hardware.
 
     PYTHONPATH=src python examples/train_indexed_lm.py [--steps 80]
 """

@@ -38,8 +38,8 @@ def all_configs() -> Dict[str, ModelConfig]:
     return {n: get_config(n) for n in ARCH_NAMES}
 
 
-# Shape-cell skip logic (DESIGN.md §Arch-applicability): long_500k needs
-# sub-quadratic sequence handling; decode shapes need a decoder.
+# Shape-cell skip logic: long_500k needs sub-quadratic sequence handling
+# (an SSM, a hybrid, or sliding-window layers).
 def cell_is_runnable(cfg: ModelConfig, shape: ShapeConfig) -> bool:
     if shape.name == "long_500k":
         return cfg.family in ("ssm", "hybrid") or bool(cfg.local_block)

@@ -2,8 +2,8 @@
 
 [arXiv:2404.16821; unverified]  80L d_model=8192 64H (GQA kv=8) d_ff=28672
 vocab=128256.  The vision tower is a STUB per instructions:
-``input_specs()`` supplies precomputed (B, 256, d_model) patch embeddings
-prepended to the token sequence; the LM backbone is real.
+the batch carries precomputed (B, 256, d_model) patch embeddings
+(``patch_embeds``) prepended to the token sequence; the LM backbone is real.
 """
 
 from .base import ModelConfig

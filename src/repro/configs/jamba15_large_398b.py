@@ -4,7 +4,7 @@
 vocab=65536, MoE 16e top-2.  Super-block of 8 layers: 7×Mamba (SSD) +
 1×attention (index 3); MoE replaces the MLP in every 2nd layer.
 
-Hardware-adaptation note (DESIGN.md §7): Jamba uses Mamba-1 selective-scan
+Hardware-adaptation note: Jamba uses Mamba-1 selective-scan
 blocks; we substitute the Mamba2 SSD chunked form (state 128) because its
 intra-chunk matmuls map onto the MXU — the published 1:7 interleave, GQA
 attention and MoE placement are preserved.

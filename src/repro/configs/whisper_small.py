@@ -2,7 +2,8 @@
 
 [arXiv:2212.04356; unverified]  12L d_model=768 12H (kv=12) d_ff=3072
 vocab=51865.  The conv frontend is a STUB per instructions:
-``input_specs()`` supplies precomputed (B, 1500, d_model) frame embeddings;
+the batch carries precomputed (B, 1500, d_model) frame embeddings
+(``frames``);
 the encoder transformer + decoder (self + cross attention) are real.
 """
 

@@ -34,8 +34,7 @@ high-concurrency serving, that failure mode is the default workload.
 Entries are evicted by record count and optionally by total cached
 bytes.  All operations are thread-safe (the extraction engine's file
 workers and the service's reader share one cache), and
-hit/miss/eviction/promotion counters are kept for the benchmarks'
-cache-hit-rate rows.
+hit/miss/eviction/promotion counters feed the service's ``stats()``.
 """
 
 from __future__ import annotations

@@ -15,9 +15,9 @@ birthday-bound expectation of n²/2h ≈ 15.7.  This module reproduces the
 * ``birthday_expectation`` — Eq. 5: E[collisions] ≈ n²/(2h).
 
 With the key width set to the paper's h ≈ 1e15 our container-scale corpora
-produce ~0 collisions (as theory says they should at n ≤ 1e6); the
-benchmarks therefore sweep the key width downward and verify measured
-collision counts track n²/2h — the same validation logic, scale-adjusted.
+produce ~0 collisions (as theory says they should at n ≤ 1e6); the tests
+therefore sweep the key width downward and check measured collision
+counts against n²/2h — the same validation logic, scale-adjusted.
 """
 
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """Algorithm 3 — index-based extraction with grouped, offset-sorted seeks.
 
 Phase 2 of the paper's architecture.  The three published optimizations
-are all here and individually switchable (so the benchmarks can ablate
-them, Table II / §IV.D):
+are all here and individually switchable (each can be ablated, Table II /
+§IV.D):
 
 1. **GroupByFilename** — one ``open()`` per file containing targets
    (477,123 potential opens → 312 in the paper).
@@ -179,7 +179,7 @@ def extract(
     pipelined engine with :data:`~repro.core.reader.DEFAULT_WORKERS`
     threads; any ``workers >= 1`` pins the engine's pool size; ``workers=0``
     runs the serial reference loop (one ``seek`` + per-line scan + per-record
-    verify) — the ablation baseline the benchmarks compare against.  Both
+    verify) — the reference the tests compare the engine against.  Both
     paths return byte-identical ``records``/``missing``/``mismatches``.
 
     ``coalesce_gap``/``span_guess`` tune the engine's pread coalescing and

@@ -3,7 +3,7 @@
 The extraction engine (:mod:`repro.core.reader`) plans *what* to read —
 coalesced ``[start, end)`` spans per file — and delegates *how* to a
 :class:`SpanBackend`.  Three backends ship, selected by
-``REPRO_READER_BACKEND`` (see :mod:`repro.flags`) or per call:
+``REPRO_READER_BACKEND`` (:func:`reader_backend`) or per call:
 
 ``uring``
     Raw ``io_uring`` submission/completion rings driven through
@@ -59,8 +59,6 @@ import sys
 import threading
 from collections import deque
 from typing import Dict, Iterable, Iterator, List, Optional, Tuple
-
-from repro import flags
 
 __all__ = [
     "RecordView",
@@ -620,6 +618,13 @@ _BACKENDS = {
 }
 
 
+def reader_backend() -> str:
+    """``REPRO_READER_BACKEND``: the backend ``auto`` stands for (default
+    ``auto``).  Read per call, not at import: tests and launchers set it
+    for one run."""
+    return os.environ.get("REPRO_READER_BACKEND", "auto")
+
+
 def resolve_backend(name: Optional[str] = None) -> SpanBackend:
     """Build a backend instance from a name (or the env default).
 
@@ -629,7 +634,7 @@ def resolve_backend(name: Optional[str] = None) -> SpanBackend:
     fds).
     """
     if name is None or name == "auto":
-        name = flags.reader_backend()
+        name = reader_backend()
     if name == "auto":
         name = "uring" if uring_available() else "thread"
     try:

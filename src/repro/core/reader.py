@@ -1,8 +1,8 @@
 """Async span engine: pluggable I/O backends, zero-copy records, batched verify.
 
 Algorithm 3's read phase, rebuilt for throughput.  The serial reference
-path (kept in :func:`repro.core.extract.extract` under ``workers=0`` for
-the ablation benchmarks) does one ``seek()`` per record, walks the file
+path (kept in :func:`repro.core.extract.extract` under ``workers=0`` as
+the tests' reference) does one ``seek()`` per record, walks the file
 line by line in Python, decodes eagerly, and re-verifies one record at a
 time.  This engine batches all four costs:
 
@@ -17,7 +17,7 @@ time.  This engine batches all four costs:
    span never stalls the window); ``thread`` is the portable blocking
    ``preadv`` fallback; ``mmap`` maps whole files and serves spans as
    windows of the page cache.  Select with ``REPRO_READER_BACKEND`` /
-   ``REPRO_READER_DEPTH`` (see :mod:`repro.flags`) or per call.
+   ``REPRO_READER_DEPTH`` (:func:`reader_depth`) or per call.
 3. **Zero-copy record views** — records are carved out of span buffers
    as :class:`~repro.core.iobackend.RecordView` memoryview windows.  No
    ``bytes`` copy of a record exists anywhere; boundary scans
@@ -53,8 +53,6 @@ import os
 from concurrent.futures import FIRST_COMPLETED, ThreadPoolExecutor, wait
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple, Union
-
-from repro import flags
 
 from .cache import RecordCache
 from .iobackend import RecordView, SpanBackend, SpanBuffer, resolve_backend
@@ -94,6 +92,23 @@ DEFAULT_MAX_SPAN = 8 * 1024 * 1024
 # Read workers: I/O-bound (pread releases the GIL), so oversubscribing a
 # small host is fine and overlaps read with verify.
 DEFAULT_WORKERS = min(8, 2 * (os.cpu_count() or 1))
+
+
+# The two env knobs are read per call, not at import: tests and launchers
+# set them for one run.
+def reader_depth() -> int:
+    """``REPRO_READER_DEPTH``: target in-flight spans per uring submission
+    window (default 32; clamped to the ring size).  Higher depths help cold
+    NVMe or networked storage; on a warm page cache it mostly bounds buffer
+    residency."""
+    return int(os.environ.get("REPRO_READER_DEPTH", "32"))
+
+
+def default_verify_backend() -> str:
+    """``REPRO_VERIFY_BACKEND``: the id recompute/compare mode ``auto``
+    stands for in :class:`~repro.core.verify.VerifyBatcher` (default
+    ``auto``: vectorized recompute, digest compare on TPU, else string)."""
+    return os.environ.get("REPRO_VERIFY_BACKEND", "auto")
 
 
 @dataclass
@@ -380,9 +395,9 @@ def stream_plan(
         be = owned_backend = resolve_backend(backend)
     stats.backend = stats.backend or be.name
     if depth is None:
-        depth = flags.reader_depth()
+        depth = reader_depth()
     if verify_backend == "auto":
-        verify_backend = flags.verify_backend()
+        verify_backend = default_verify_backend()
     vf = verifier if verifier is not None else VerifyBatcher(verify_backend)
     args = dict(
         verify=verify,

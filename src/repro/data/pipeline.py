@@ -4,7 +4,7 @@ The byte-offset index IS the dataset: examples are addressed
 ``example idx → record key → (file, byte_offset) → seek``.  Per step each
 dp shard's fetches are **grouped by file and sorted by ascending offset**
 — Algorithm 3's access-pattern optimization reapplied verbatim to the
-training loader (DESIGN.md §2).
+training loader.
 
 Production concerns implemented here:
 

@@ -2,7 +2,8 @@
 
 ``(step, dp_rank)`` → global example ids → record keys → byte offsets is a
 *pure function*: no iterator state exists anywhere.  Consequences, which
-are the data-plane half of the fault-tolerance story (DESIGN.md §2):
+are the data-plane half of the fault-tolerance story
+(:mod:`repro.runtime.fault`):
 
 * checkpointing the data pipeline = saving one integer (the step);
 * any worker can compute any other worker's shard (failure hand-off);

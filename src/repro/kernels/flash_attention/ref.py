@@ -70,17 +70,12 @@ def flash_attention_chunked(
 ) -> jax.Array:
     """Online-softmax attention in pure XLA (flash attention without Pallas).
 
-    Beyond-paper §Perf optimization (EXPERIMENTS.md): scans over KV chunks
-    carrying (m, l, acc), so peak score memory is (B, H, Sq, chunk) instead
-    of (B, H, Sq, Skv) — the S×S materialization that made every train/
-    prefill cell memory-bound in the baseline dry-run disappears.  GQA is
-    computed in grouped form (no repeated K/V materialization).  Matmuls
-    run in the input dtype with f32 accumulation (MXU-native).
-
-    The chunk loop is a ``lax.scan`` honoring ``flags.scan_unroll()`` so the
-    dry-run's roofline probes count every chunk (see launch/dryrun.py).
+    Scans over KV chunks carrying (m, l, acc), so peak score memory is
+    (B, H, Sq, chunk) instead of the (B, H, Sq, Skv) that
+    :func:`flash_attention_ref` materializes.  K and V are repeated to the
+    query heads one chunk at a time.  Matmuls run in the input dtype with
+    f32 accumulation (MXU-native).
     """
-    from repro import flags
     from repro.dist.logical import constrain
 
     b, hq, sq, d = q.shape
@@ -146,7 +141,6 @@ def flash_attention_chunked(
     (m_f, l_f, acc), _ = lax.scan(
         step, (m0, l0, a0),
         (kc, vc, jnp.arange(nkc)),
-        unroll=flags.scan_unroll(),
     )
     l_safe = jnp.where(l_f > 0, l_f, 1.0)
     out = acc / l_safe[..., None]

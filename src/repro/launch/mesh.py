@@ -1,8 +1,8 @@
-"""Production mesh construction.
+"""Mesh construction.
 
-A FUNCTION, not a module-level constant — importing this module must never
-touch jax device state (the dry-run sets XLA_FLAGS before first jax init;
-smoke tests and benches must keep seeing 1 device).
+Functions, not module-level constants: importing this module must never
+touch jax device state (a process that fakes host devices sets XLA_FLAGS
+before jax first initializes).
 
 Every mesh is built here, with Auto axes: the models place activations
 with ``with_sharding_constraint`` (:func:`repro.dist.logical.constrain`),
@@ -17,14 +17,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_mesh", "mesh_from_str", "dp_axes"]
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+__all__ = ["make_mesh", "mesh_from_str", "dp_axes"]
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...],

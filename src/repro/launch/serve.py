@@ -1,8 +1,7 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id> …``.
 
-Builds the engine for the requested architecture (reduced config on CPU;
-the dry-run proves the full configs lower for the decode shapes) and
-serves a batch of prompts, reporting prefill/decode timings.
+Builds the engine for the requested architecture (reduced config on CPU)
+and serves a batch of prompts, reporting prefill/decode timings.
 
 ``--mesh DATAxMODEL`` serves sharded: params go to their logical-rule
 shardings (:mod:`repro.dist.logical`), the request batch spreads over the

@@ -15,7 +15,6 @@ __all__ = [
     "abstract",
     "shardings_from_specs",
     "batch_shardings",
-    "state_shardings",
     "replicated",
 ]
 
@@ -101,13 +100,3 @@ def batch_shardings(mesh: Mesh, batch_specs: Dict[str, jax.ShapeDtypeStruct]):
         return NamedSharding(mesh, P(dp_entry, *([None] * (nd - 1))))
 
     return {k: one(v) for k, v in batch_specs.items()}
-
-
-def state_shardings(mesh: Mesh, param_specs: PyTree):
-    """TrainState shardings: params + mirrored adam m/v + scalar step."""
-    ps = shardings_from_specs(mesh, param_specs)
-    return {
-        "params": ps,
-        "opt": {"m": ps, "v": ps, "count": replicated(mesh)},
-        "step": replicated(mesh),
-    }

@@ -3,8 +3,8 @@
 Wires the full stack for a real run: corpus/index data plane → model from
 the assigned-architecture registry → sharded train step on the requested
 mesh → catalog checkpoints + heartbeats.  On the CPU container the mesh is
-(1,1) and the reduced smoke config is the default; on a pod, pass
-``--full-config --mesh 16x16`` (the dry-run proves those lower).
+(1,1) and the reduced smoke config is the default; on a TPU host, pass
+``--full-config`` and ``--mesh DATAxMODEL``.
 """
 
 from __future__ import annotations

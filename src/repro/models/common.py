@@ -25,6 +25,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
@@ -57,9 +58,25 @@ __all__ = [
     "last_token_logits",
     "chunked_xent",
     "param_count",
+    "RESIDUAL_AXES",
+    "REMAT_POLICY",
 ]
 
 PyTree = Any
+
+# Attention, MLP and mixer outputs are constrained to the sequence-split
+# residual layout (Megatron sequence parallelism): GSPMD then lowers the
+# tensor-parallel combine as a reduce-scatter, half the wire bytes of an
+# all-reduce, and the norms and residual adds between layers run split.
+RESIDUAL_AXES = ("batch", "seq_sp", None)
+
+# The layer scans' remat policy: save each layer's attention, MLP and mixer
+# outputs (taken after the tensor-parallel combine), so the backward pass
+# re-runs neither the forward's combines nor its matmuls, for two
+# sequence-split saves a layer.
+REMAT_POLICY = jax.checkpoint_policies.save_only_these_names(
+    "attn_out", "ffn_out", "mixer_out"
+)
 
 
 def compute_dtype(cfg: ModelConfig):
@@ -304,70 +321,8 @@ def attention_apply(
     )                                              # (B, H, S, Dh)
     out = jnp.swapaxes(out, 1, 2).reshape(b, s, h * dh)
     out = out @ params["wo"].astype(cdt)
-    from repro import flags as _flags
-    from jax.ad_checkpoint import checkpoint_name
-
-    out = constrain(out, *_flags.residual_axes())
+    out = constrain(out, *RESIDUAL_AXES)
     return checkpoint_name(out, "attn_out")
-
-
-def decode_attention_chunked(
-    q,          # (B, H, Dh)
-    k_cache,    # (B, Hkv, S, Dh)
-    v_cache,    # (B, Hkv, S, Dh)
-    valid,      # (B, S) bool
-    chunk: int = 2048,
-):
-    """One-token GQA attention over a cache, online-softmax over chunks.
-
-    §Perf iteration 2 for the decode cells: the unchunked path materializes
-    (B, H, S) f32 score/softmax tensors ~20× larger than the cache slice it
-    reads; scanning KV chunks with an (m, l, acc) carry caps the live
-    intermediate at (B, H, chunk) — the decode analogue of flash attention,
-    in pure XLA.  Chunk loop honours flags.scan_unroll() (roofline probes).
-    """
-    from repro import flags as _flags
-
-    b, h, dh = q.shape
-    hkv, s = k_cache.shape[1], k_cache.shape[2]
-    g = h // hkv
-    c = min(chunk, s)
-    pad = (c - s % c) % c
-    if pad:
-        k_cache = jnp.pad(k_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v_cache = jnp.pad(v_cache, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        valid = jnp.pad(valid, ((0, 0), (0, pad)))
-    nch = (s + pad) // c
-    qg = (q / math.sqrt(dh)).reshape(b, hkv, g, dh).astype(k_cache.dtype)
-    kc = k_cache.reshape(b, hkv, nch, c, dh).transpose(2, 0, 1, 3, 4)
-    vc = v_cache.reshape(b, hkv, nch, c, dh).transpose(2, 0, 1, 3, 4)
-    valc = valid.reshape(b, nch, c).transpose(1, 0, 2)
-
-    def step(carry, xs):
-        m_prev, l_prev, acc = carry
-        kb, vb, vm = xs
-        sc = jnp.einsum(
-            "bkgd,bkcd->bkgc", qg, kb, preferred_element_type=jnp.float32
-        )
-        sc = jnp.where(vm[:, None, None, :], sc, -1e30)
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1))
-        p = jnp.exp(sc - m_new[..., None]) * vm[:, None, None, :]
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = alpha * l_prev + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "bkgc,bkcd->bkgd", p.astype(vb.dtype), vb,
-            preferred_element_type=jnp.float32,
-        )
-        return (m_new, l_new, acc), None
-
-    m0 = jnp.full((b, hkv, g), -1e30, jnp.float32)
-    l0 = jnp.zeros((b, hkv, g), jnp.float32)
-    a0 = jnp.zeros((b, hkv, g, dh), jnp.float32)
-    (_, l_f, acc), _ = jax.lax.scan(
-        step, (m0, l0, a0), (kc, vc, valc), unroll=_flags.scan_unroll()
-    )
-    l_safe = jnp.where(l_f > 0, l_f, 1.0)
-    return (acc / l_safe[..., None]).reshape(b, h, dh)  # f32
 
 
 def attention_decode(
@@ -413,19 +368,11 @@ def attention_decode(
         # ring buffer: slot s holds token t = pos - ((pos - s) mod W)
         t = pos[:, None] - (pos[:, None] - idx) % window
         valid = t >= 0
-    from repro import flags as _flags
-
-    # §Perf note (EXPERIMENTS.md, decode iteration 2 — REFUTED): chunking
-    # the decode cache breaks its (batch, seq→model) sharding: the
-    # reshape/transpose reshards ~5 GB of cache per layer (collective term
-    # 0→3.4 s).  The unchunked einsum+softmax is already GSPMD's
-    # flash-decoding pattern (per-shard partial softmax + scalar combines),
-    # so it stays the default; REPRO_DECODE_CHUNKED=1 exists for
-    # single-device serving experiments.
-    if _flags.DECODE_CHUNKED:
-        ctx = decode_attention_chunked(q, k_cache, v_cache, valid)
-    else:
-        ctx = decode_attention(q, k_cache, v_cache, valid)  # (B,Hkv,G,Dh)
+    # unchunked on purpose: under a (batch, seq -> model) cache sharding a
+    # chunk reshape would reshard the cache every layer, while this
+    # einsum + softmax is GSPMD's flash-decoding pattern (per-shard partial
+    # softmax and scalar combines)
+    ctx = decode_attention(q, k_cache, v_cache, valid)  # (B,Hkv,G,Dh)
     ctx = ctx.reshape(b, h * dh).astype(cdt)
     out = (ctx @ params["wo"].astype(cdt))[:, None, :]  # (B,1,D)
     return out, {"k": k_cache, "v": v_cache}
@@ -525,10 +472,7 @@ def mlp_apply(params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     u = x @ params["wu"].astype(cdt)
     h = constrain(g * u, "batch", "seq", "d_ff")
     out = h @ params["wd"].astype(cdt)
-    from repro import flags as _flags
-    from jax.ad_checkpoint import checkpoint_name
-
-    out = constrain(out, *_flags.residual_axes())
+    out = constrain(out, *RESIDUAL_AXES)
     return checkpoint_name(out, "ffn_out")
 
 
@@ -666,17 +610,7 @@ def chunked_xent(
         nll = (lse - tgt) * mx
         return jnp.sum(nll)
 
-    from repro import flags
-
-    if flags.unrolling():
-        # dry-run roofline probes: XLA cost_analysis counts loop bodies
-        # once, so unroll the chunk loop at trace time
-        total = jnp.zeros((), jnp.float32)
-        for i in range(n_chunks):
-            total = total + one((hs[i], ts[i], ms[i]))
-        losses = total
-    else:
-        losses = jnp.sum(lax.map(one, (hs, ts, ms)))
+    losses = jnp.sum(lax.map(one, (hs, ts, ms)))
     denom = jnp.maximum(jnp.sum(mask), 1.0)
     return losses / denom
 

@@ -1,8 +1,8 @@
 """Encoder–decoder transformer (Whisper family).
 
 The conv audio frontend is a stub per instructions: the encoder consumes
-precomputed (B, frames, d_model) frame embeddings (``input_specs`` supplies
-them).  Encoder: bidirectional self-attention stack.  Decoder: causal
+precomputed (B, frames, d_model) frame embeddings (the batch's
+``frames``).  Encoder: bidirectional self-attention stack.  Decoder: causal
 self-attention (RoPE — adaptation note: Whisper's learned positional
 embeddings cap at 448 tokens; RoPE makes the assigned 32k decode shapes
 well-defined) + cross-attention to the encoder output + MLP.
@@ -20,10 +20,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import flags
 from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.models.common import (
+    REMAT_POLICY,
     _qkv,
     apply_rope,
     attend,
@@ -112,8 +112,8 @@ def encode(params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
         x = x + mlp_apply(blk["mlp"], cfg, h)
         return x, None
 
-    body = jax.checkpoint(body, policy=flags.remat_policy())
-    x, _ = lax.scan(body, x, params["enc_blocks"], unroll=flags.scan_unroll())
+    body = jax.checkpoint(body, policy=REMAT_POLICY)
+    x, _ = lax.scan(body, x, params["enc_blocks"])
     return rmsnorm(x, params["enc_norm"], cfg.norm_eps)
 
 
@@ -136,8 +136,8 @@ def encdec_forward(params, cfg: ModelConfig, frames, tokens):
         x = constrain(x, "batch", "seq_sp", None)
         return _dec_layer(blk, cfg, x, positions, enc_out), None
 
-    body = jax.checkpoint(body, policy=flags.remat_policy())
-    x, _ = lax.scan(body, x, params["dec_blocks"], unroll=flags.scan_unroll())
+    body = jax.checkpoint(body, policy=REMAT_POLICY)
+    x, _ = lax.scan(body, x, params["dec_blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return constrain(x, "batch", "seq", None)
 
@@ -227,7 +227,7 @@ def encdec_prefill(params, cfg: ModelConfig, frames, tokens, max_len=None,
         x = x + mlp_apply(blk["mlp"], cfg, h)
         return x, {"self": self_kv, "cross": cross_kv}
 
-    x, cache = lax.scan(body, x, params["dec_blocks"], unroll=flags.scan_unroll())
+    x, cache = lax.scan(body, x, params["dec_blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = last_token_logits(params["embed"], cfg, x, lengths=lengths)
     return logits, cache
@@ -273,7 +273,6 @@ def encdec_decode_step(params, cfg: ModelConfig, token, pos, cache):
 
     x, self_new = lax.scan(
         body, x, (params["dec_blocks"], cache["self"], cache["cross"]),
-        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]

@@ -17,11 +17,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import flags
 from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.models import moe as moe_mod
 from repro.models.common import (
+    REMAT_POLICY,
+    RESIDUAL_AXES,
     attend,
     attention_decode,
     attention_init,
@@ -150,10 +151,9 @@ def hybrid_forward(params, cfg: ModelConfig, tokens: jax.Array):
         x, a = _apply_block(blk, cfg, x, positions)
         return (x, aux + a), None
 
-    body = jax.checkpoint(body, policy=flags.remat_policy())
+    body = jax.checkpoint(body, policy=REMAT_POLICY)
     (x, aux), _ = lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["blocks"],
-        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return constrain(x, "batch", "seq", None), aux
@@ -232,7 +232,7 @@ def hybrid_prefill(params, cfg: ModelConfig, tokens, max_len: Optional[int] = No
                 att = attend(jnp.swapaxes(q, 1, 2), kc, vc, causal=True)
                 att = jnp.swapaxes(att, 1, 2).reshape(b, s, -1)
                 x = x + constrain(
-                    att @ blk["attn"]["wo"].astype(cdt), *flags.residual_axes()
+                    att @ blk["attn"]["wo"].astype(cdt), *RESIDUAL_AXES
                 )
             else:
                 mp = jax.tree_util.tree_map(lambda v: v[mi], blk["mamba"])
@@ -255,7 +255,7 @@ def hybrid_prefill(params, cfg: ModelConfig, tokens, max_len: Optional[int] = No
         )
         return x, {"attn": kv_out, "mamba": stacked_states}
 
-    x, cache = lax.scan(body, x, params["blocks"], unroll=flags.scan_unroll())
+    x, cache = lax.scan(body, x, params["blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = last_token_logits(params["embed"], cfg, x, lengths=lengths)
     return logits, cache
@@ -297,7 +297,6 @@ def hybrid_decode_step(params, cfg: ModelConfig, token, pos, cache):
 
     x, (kv_new, m_new) = lax.scan(
         body, x, (params["blocks"], cache["attn"], cache["mamba"]),
-        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]

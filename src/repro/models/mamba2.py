@@ -19,11 +19,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.kernels.ssd_scan.ops import ssd_scan
-from repro.models.common import compute_dtype, rmsnorm
+from repro.models.common import RESIDUAL_AXES, compute_dtype, rmsnorm
 
 __all__ = ["mamba_init", "mamba_apply", "mamba_decode", "mamba_state_init"]
 
@@ -175,10 +176,7 @@ def mamba_apply(
     out = y @ params["out_proj"].astype(cdt)
     if pad:
         out = out[:, :s_true]
-    from repro import flags as _flags
-    from jax.ad_checkpoint import checkpoint_name
-
-    out = checkpoint_name(constrain(out, *_flags.residual_axes()), "mixer_out")
+    out = checkpoint_name(constrain(out, *RESIDUAL_AXES), "mixer_out")
     if not return_state:
         return out
     # final recurrent state = decay_last * prefix_last + states_last

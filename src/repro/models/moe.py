@@ -1,6 +1,6 @@
 """Mixture-of-Experts layer: expert parallelism via shard_map.
 
-Pattern (DESIGN.md §3): activations enter the MoE layer replicated over the
+Pattern: activations enter the MoE layer replicated over the
 "model" mesh axis (batch sharded over dp); expert weights are sharded
 ``experts → model`` (+ ``d_model → data`` FSDP).  Because every model shard
 sees all (local-batch) tokens, dispatch needs **no all-to-all** — each

@@ -1,15 +1,14 @@
 """Uniform model API over all assigned architecture families.
 
 ``build_model(cfg)`` returns a :class:`ModelApi` with the same five entry
-points regardless of family — the trainer, serving engine, dry-run and
-benchmarks program against this interface only:
+points regardless of family — the trainer, the serving engines and the
+chip benchmark program against this interface only:
 
   init(key)                       → (params, param_specs)
   loss(params, batch)             → (scalar, metrics)       [train_step core]
   prefill(params, batch, max_len) → (last_logits, cache)
   decode_step(params, token, pos, cache) → (logits, cache)  [serve_step core]
   cache_init(batch, max_len)      → (cache, cache_specs)
-  input_specs(shape)              → dict of ShapeDtypeStructs (dry-run)
 
 ``batch["lengths"]`` (B,) in prefill gathers each sequence's true
 last-prompt-position logits, so ragged right-padded batches don't start
@@ -31,12 +30,9 @@ elsewhere; the continuous engine refuses politely):
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Optional
 
-import jax
-import jax.numpy as jnp
-
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 
 from . import encdec, hybrid, ssm, transformer
 
@@ -62,37 +58,6 @@ class ModelApi:
     @property
     def supports_paged(self) -> bool:
         return self.decode_step_paged is not None
-
-    def input_specs(self, shape: ShapeConfig) -> Dict[str, jax.ShapeDtypeStruct]:
-        """ShapeDtypeStruct stand-ins for every model input of this cell.
-
-        No device allocation — the same pattern the dry-run uses for full
-        production configs (weak-type-correct, shardable).
-        """
-        cfg = self.cfg
-        b, s = shape.global_batch, shape.seq_len
-        f32 = jnp.float32
-        i32 = jnp.int32
-        if shape.kind in ("train", "prefill"):
-            specs: Dict[str, jax.ShapeDtypeStruct] = {}
-            s_txt = s - (cfg.n_img_tokens or 0)
-            specs["tokens"] = jax.ShapeDtypeStruct((b, s_txt), i32)
-            if shape.kind == "train":
-                specs["loss_mask"] = jax.ShapeDtypeStruct((b, s_txt), f32)
-            if cfg.family == "encdec":
-                specs["frames"] = jax.ShapeDtypeStruct(
-                    (b, cfg.enc_frames, cfg.d_model), f32
-                )
-            if cfg.family == "vlm":
-                specs["patch_embeds"] = jax.ShapeDtypeStruct(
-                    (b, cfg.n_img_tokens, cfg.d_model), f32
-                )
-            return specs
-        # decode: one new token against a cache of seq_len
-        return {
-            "token": jax.ShapeDtypeStruct((b, 1), i32),
-            "pos": jax.ShapeDtypeStruct((b,), i32),
-        }
 
 
 def _transformer_api(cfg: ModelConfig) -> ModelApi:

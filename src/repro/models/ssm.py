@@ -13,10 +13,10 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import flags
 from repro.configs.base import ModelConfig
 from repro.dist.logical import constrain
 from repro.models.common import (
+    REMAT_POLICY,
     chunked_xent,
     compute_dtype,
     embed_apply,
@@ -71,8 +71,8 @@ def ssm_forward(params, cfg: ModelConfig, tokens: jax.Array):
         x = x + mamba_apply(blk["mamba"], cfg, h)
         return x, None
 
-    body = jax.checkpoint(body, policy=flags.remat_policy())
-    x, _ = lax.scan(body, x, params["blocks"], unroll=flags.scan_unroll())
+    body = jax.checkpoint(body, policy=REMAT_POLICY)
+    x, _ = lax.scan(body, x, params["blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return constrain(x, "batch", "seq", None), jnp.zeros((), jnp.float32)
 
@@ -106,7 +106,7 @@ def ssm_prefill(params, cfg: ModelConfig, tokens, max_len: Optional[int] = None,
         y, st = mamba_apply(blk["mamba"], cfg, h, return_state=True)
         return x + y, st
 
-    x, cache = lax.scan(body, x, params["blocks"], unroll=flags.scan_unroll())
+    x, cache = lax.scan(body, x, params["blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = last_token_logits(params["embed"], cfg, x, lengths=lengths)
     return logits, cache
@@ -121,9 +121,7 @@ def ssm_decode_step(params, cfg: ModelConfig, token, pos, cache):
         y, st_new = mamba_decode(blk["mamba"], cfg, h, st)
         return x + y, st_new
 
-    x, new_cache = lax.scan(
-        body, x, (params["blocks"], cache), unroll=flags.scan_unroll()
-    )
+    x, new_cache = lax.scan(body, x, (params["blocks"], cache))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]
     return logits, new_cache

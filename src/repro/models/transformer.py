@@ -1,12 +1,13 @@
 """Decoder-only LM: dense / local-global (gemma3) / MoE / VLM families.
 
-Layer stacks are ``lax.scan`` over stacked weights (compile-time constant
-HLO regardless of depth — essential for the 66-cell dry-run).  Uniform
-archs scan over single layers; gemma3 scans over blocks of
-``local_block`` layers (5 sliding-window + 1 global).  Remat wraps the
-scanned body (nothing saved inside a layer); the carried residual stream
+Layer stacks are ``lax.scan`` over stacked weights (the HLO, and so the
+compile time, does not grow with depth).  Uniform archs scan over single
+layers; gemma3 scans over blocks of ``local_block`` layers (5
+sliding-window + 1 global).  Remat wraps the scanned body and saves only
+each layer's attention and MLP outputs
+(:data:`~repro.models.common.REMAT_POLICY`); the carried residual stream
 is sequence-sharded over "model" (logical axis ``seq_sp``) so the saved
-activations per chip stay small (DESIGN.md §3).
+activations per chip stay small.
 
 Entry points: ``init_lm``, ``lm_loss`` (train), ``lm_prefill`` (forward +
 KV cache build), ``lm_decode_step`` (one-token serve), ``lm_cache_init``.
@@ -22,11 +23,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro import flags
 from repro.configs.base import ModelConfig
 from repro.dist.logical import axis_rules, constrain
 from repro.models import moe as moe_mod
 from repro.models.common import (
+    REMAT_POLICY,
+    RESIDUAL_AXES,
     attend,
     attention_apply,
     attention_decode,
@@ -180,10 +182,9 @@ def lm_forward(
         x = constrain(x, "batch", "seq_sp", None)
         return (x, aux), None
 
-    body = jax.checkpoint(body, policy=flags.remat_policy())
+    body = jax.checkpoint(body, policy=REMAT_POLICY)
     (x, aux), _ = lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), params["blocks"],
-        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     return constrain(x, "batch", "seq", None), aux
@@ -313,7 +314,7 @@ def lm_prefill(
         )
         attn = jnp.swapaxes(attn, 1, 2).reshape(x.shape[0], s, -1)
         x = x + constrain(
-            attn @ p["attn"]["wo"].astype(cdt), *flags.residual_axes()
+            attn @ p["attn"]["wo"].astype(cdt), *RESIDUAL_AXES
         )
         h2 = rmsnorm(x, p["ln2"], cfg.norm_eps)
         if "moe" in p:
@@ -336,7 +337,7 @@ def lm_prefill(
                 kvs[f"pos{i}"] = kv
         return x, kvs
 
-    x, cache = lax.scan(body, x, params["blocks"], unroll=flags.scan_unroll())
+    x, cache = lax.scan(body, x, params["blocks"])
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     offset = extra_embeds.shape[1] if extra_embeds is not None else 0
     logits = last_token_logits(
@@ -381,9 +382,7 @@ def lm_decode_step(
                 new_kvs[f"pos{i}"] = kv
         return x, new_kvs
 
-    x, new_cache = lax.scan(
-        body, x, (params["blocks"], cache), unroll=flags.scan_unroll()
-    )
+    x, new_cache = lax.scan(body, x, (params["blocks"], cache))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]
     return logits, new_cache
@@ -475,7 +474,6 @@ def lm_decode_step_paged(
 
     (x, new_cache), _ = lax.scan(
         body, (x, cache), (params["blocks"], jnp.arange(n_steps)),
-        unroll=flags.scan_unroll(),
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = unembed_logits(params["embed"], cfg, x)[:, 0]
@@ -594,9 +592,7 @@ def lm_prefill_suffix(
                 new_kvs[f"pos{i}"] = kv
         return x, new_kvs
 
-    x, new_cache = lax.scan(
-        body, x, (params["blocks"], cache), unroll=flags.scan_unroll()
-    )
+    x, new_cache = lax.scan(body, x, (params["blocks"], cache))
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = last_token_logits(params["embed"], cfg, x, lengths=lengths)
     return logits, new_cache

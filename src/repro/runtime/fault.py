@@ -10,8 +10,8 @@ through the same mechanism a TPU-pod deployment uses in miniature:
   than ``timeout`` is declared dead;
 * recovery = restart from the latest **catalog checkpoint** with the
   surviving worker count: the deterministic sampler re-partitions the
-  global example order over the new dp extent (no data loss / no
-  duplication — DESIGN.md §2), and the mesh is re-carved via
+  global example order over the new dp extent (no data loss, no
+  duplication: :mod:`repro.data.sampler`), and the mesh is re-carved via
   ``make_mesh`` with the surviving shape.
 
 ``run_with_failures`` drives a train function through injected failures

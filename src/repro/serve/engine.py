@@ -1,19 +1,18 @@
 """Batched serving engine: prefill + decode over the uniform model API.
 
-Static-batch engine (the dry-run's ``serve_step`` is its inner loop): a
-batch of requests is padded to a common prefill length, prefilled once,
-then decoded token-by-token with per-sequence positions until EOS or the
-token budget.  Per-sequence positions (not a scalar clock) are what real
-continuous-batching serving needs — finished sequences keep their cache
-rows and are masked out of sampling.
+Static-batch engine: a batch of requests is padded to a common prefill
+length, prefilled once, then decoded token-by-token with per-sequence
+positions until EOS or the token budget.  Per-sequence positions (not a
+scalar clock) are what real continuous-batching serving needs — finished
+sequences keep their cache rows and are masked out of sampling.
 
 Sharded serving: pass ``mesh`` (and the ``param_specs`` returned by
 ``api.init``) and the engine device_puts the weights to their logical
 shardings, shards the batch over the data-parallel axes, and runs prefill
 and every decode step inside the mesh context so the models' ``constrain``
 annotations (:mod:`repro.dist.logical`) take effect — batched decode then
-shards across devices exactly like the dry-run's serve cells.  Without a
-mesh nothing changes: single-device serving traces the identical jaxpr.
+shards across devices.  Without a mesh nothing changes: single-device
+serving traces the identical jaxpr.
 """
 
 from __future__ import annotations

@@ -1,7 +1,7 @@
 """Token-level continuous batching over the paged KV cache.
 
-The static :class:`~repro.serve.engine.Engine` serves a batch the way the
-dry-run does: pad every prompt to a common length, prefill once, decode
+The static :class:`~repro.serve.engine.Engine` serves a batch whole: pad
+every prompt to a common length, prefill once, decode
 until the LAST sequence finishes.  Real serving traffic is ragged — a
 handful of long generations pin the batch while short ones sit finished
 in their rows, and newly arrived requests wait for the whole batch to

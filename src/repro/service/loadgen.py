@@ -23,8 +23,9 @@ flagged in the batch's degraded mask"), and served-clean requests.  A
 exits) attributes service-side fault counters — hedges fired, retries,
 degraded keys — to exactly this run's window.
 
-Used by ``benchmarks/service_load.py`` (BENCH_service.json) and the
-``repro.launch.serve_index`` launcher's ``--load`` / ``--chaos`` modes.
+Used by the ``repro.launch.serve_index`` launcher's ``--load`` /
+``--chaos`` modes and by the fault-tolerance tests.  The chip benchmark
+has its own generator (``bench/loadgen.py``).
 """
 
 from __future__ import annotations

@@ -2,8 +2,8 @@
 
 ``make_train_step`` closes over the model API and optimizer config and
 returns a pure ``(state, batch) → (state, metrics)`` function — exactly
-what gets ``jax.jit``-ed with in/out shardings by the launcher and the
-multi-pod dry-run.  Microbatch gradient accumulation (``grad_accum > 1``)
+what gets ``jax.jit``-ed with in/out shardings by the launcher.
+Microbatch gradient accumulation (``grad_accum > 1``)
 runs as a ``lax.scan`` over microbatches with an fp32 grad accumulator.
 
 Optional gradient compression (int8 + error feedback) hooks in between
@@ -73,14 +73,11 @@ def make_train_step(
             )
             return (acc, loss_acc + loss), metrics
 
-        from repro import flags
-
         zeros = jax.tree_util.tree_map(
             lambda p: jnp.zeros(p.shape, jnp.float32), params
         )
         (grads, loss_sum), metrics = lax.scan(
             body, (zeros, jnp.zeros((), jnp.float32)), jnp.arange(grad_accum),
-            unroll=flags.scan_unroll(),
         )
         grads = jax.tree_util.tree_map(lambda g: g / grad_accum, grads)
         metrics = jax.tree_util.tree_map(lambda m: m[-1], metrics)

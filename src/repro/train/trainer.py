@@ -3,7 +3,7 @@ checkpoints, with restart/elastic recovery built in.
 
 This is the driver behind ``examples/train_indexed_lm.py`` and the
 fault-tolerance tests.  On the container it runs on the 1-device mesh;
-on a pod the identical object runs under ``make_production_mesh()`` —
+on a mesh (:func:`repro.launch.mesh.make_mesh`) the identical object runs —
 the mesh and the dp extent are constructor parameters, everything else
 (sampler addressing, checkpoint format, step function) is mesh-agnostic.
 """

@@ -240,6 +240,58 @@ def test_collisions_from_pairs_distinctness():
     assert got == {"K2": ["a", "b"]}  # duplicates of same id are NOT collisions
 
 
+def test_verification_reports_each_collision_as_a_mismatch(corpus):
+    """§VI.A's discovery path: with 16-bit hashed keys some targets resolve
+    to another molecule's record; verification reports each as a mismatch,
+    never as a record, and each is a true key collision.  The full-id
+    pipeline (§VI.C) returns every target, clean."""
+    store, spec = corpus
+    targets = db_id_list(spec, "chembl")
+    colliding = scan_corpus(store, key_bits=16).colliding
+    idx_h = build_index(store, key_mode="hashed_key", key_bits=16,
+                        recompute_keys=True)
+    res_h = extract(store, idx_h, targets, key_bits=16)
+    assert res_h.mismatches
+    assert res_h.found + len(res_h.mismatches) == len(targets)
+    for m in res_h.mismatches:
+        assert m.expected_id != m.found_id
+        assert m.expected_id not in res_h.records
+        assert hashed_key(m.expected_id, 16) == m.lookup_key
+        assert hashed_key(m.found_id, 16) == m.lookup_key
+        assert {m.expected_id, m.found_id} <= set(colliding[m.lookup_key])
+    res_f = extract(store, build_index(store, key_mode="full_id"), targets)
+    assert res_f.found == len(targets)
+    assert not res_f.mismatches and not res_f.missing
+
+
+def test_collisions_shrink_with_key_width(corpus):
+    """Eq. 4-5: keys of every width truncate one digest, so ids that collide
+    at b bits collide at every narrower width; at the paper's 50 bits
+    (h ≈ 1e15, E = n²/2h ≈ 1e-9 here) this corpus has none."""
+    store, _ = corpus
+    affected = [
+        set().union(*scan_corpus(store, key_bits=b).colliding.values())
+        for b in (16, 20, 24, 28, 50)
+    ]
+    assert affected[0]
+    for narrow, wide in zip(affected, affected[1:]):
+        assert wide <= narrow
+    assert not affected[-1]
+
+
+def test_full_id_index_costs_more_storage_than_hashed_keys(corpus, tmp_path):
+    """Table IV's storage column: the hashed key is a fixed 27 characters,
+    every full id is longer, so the same records' full-id index is the
+    larger one on disk — the price of deterministic uniqueness."""
+    store, spec = corpus
+    idx_h = build_index(store, key_mode="hashed_key")
+    idx_f = build_index(store, key_mode="full_id")
+    assert len(idx_h) == len(idx_f) == spec.n_records
+    assert {len(k) for k in idx_h.entries} == {27}
+    assert min(len(k) for k in idx_f.entries) > 27
+    assert idx_f.save_csv(tmp_path / "f.csv") > idx_h.save_csv(tmp_path / "h.csv")
+
+
 # ---------------------------------------------------------------------------
 # intersection (Eq. 1)
 # ---------------------------------------------------------------------------

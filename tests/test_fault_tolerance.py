@@ -686,6 +686,58 @@ def test_service_fetch_survives_dead_shard(corpus, store_dir):
     rt.close()
 
 
+def test_service_closed_loop_survives_a_shard_killed_mid_run(corpus, store_dir):
+    """Closed-loop clients through the service while one shard range is
+    killed and revived mid-run: no request fails (the outage shows only as
+    degraded results), the degraded mask is exactly the dead shard's keys,
+    and after revival the service answers as the store does."""
+    rstore, _ = corpus
+    rt, inj = _chaos_router(store_dir, fail_threshold=2)
+    keys = sorted(IndexStore.open(store_dir).iter_keys())
+    sid = shard_of(digest_u64(keys), rt.n_shards, rt.digest_bits)
+    dead_shard = 3
+    with QueryService(rstore, rt, ServiceConfig(replicas=2)) as svc:
+        svc.lookup_batch(keys[:300])  # warm
+
+        def chaos():
+            time.sleep(0.2)
+            for tr in inj:
+                tr.kill(shard=dead_shard)
+            time.sleep(0.4)
+            for tr in inj:
+                tr.revive(shard=dead_shard)
+
+        chaos_thread = threading.Thread(target=chaos)
+        chaos_thread.start()
+        rep = run_closed_loop(
+            svc.lookup_batch, keys, clients=6, duration_s=1.0,
+            keys_per_request=8, classify=lambda r: bool(r.degraded.any()),
+        )
+        chaos_thread.join()
+        assert rep.errors == 0, f"{rep.errors} client errors during chaos"
+        assert rep.degraded > 0, "the kill window produced no degraded result"
+
+        for tr in inj:
+            tr.kill(shard=dead_shard)
+        res = svc.lookup_batch(keys)
+        assert np.array_equal(res.degraded, sid == dead_shard)
+        assert res.hit[sid != dead_shard].all()
+        for tr in inj:
+            tr.revive(shard=dead_shard)
+
+        ref = IndexStore.open(store_dir).lookup_batch(keys)
+        deadline = time.monotonic() + 10.0
+        res = svc.lookup_batch(keys)
+        while res.degraded.any() and time.monotonic() < deadline:
+            time.sleep(0.1)
+            res = svc.lookup_batch(keys)
+        assert not res.degraded.any(), "still degraded 10 s after revival"
+        for got, want in zip((res.file_ids, res.offsets, res.hit), ref):
+            assert np.array_equal(got, want)
+        assert svc.stats()["health"]["revivals"] >= 1
+    rt.close()
+
+
 def test_loadgen_separates_failed_degraded_and_counters():
     calls = [0]
 

@@ -16,6 +16,7 @@ from repro.kernels.hash_mix.ref import hash_mix_ref
 from repro.kernels.sorted_probe.ops import sorted_probe_pallas
 from repro.kernels.sorted_probe.ref import sorted_probe_ref, sort_pairs
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import (
     flash_attention_chunked,
     flash_attention_ref,
@@ -294,7 +295,7 @@ def test_flash_attention_suffix_rows_bit_identical_to_full():
 ])
 def test_flash_attention_chunked_matches_ref(b, hq, hkv, sq, skv, d,
                                              causal, window, chunk):
-    """The XLA online-softmax path (the §Perf default) vs the oracle."""
+    """The XLA online-softmax path vs the oracle."""
     rng = np.random.default_rng(b * 31 + sq)
     q = jnp.asarray(rng.standard_normal((b, hq, sq, d)).astype(np.float32))
     k = jnp.asarray(rng.standard_normal((b, hkv, skv, d)).astype(np.float32))
@@ -304,6 +305,24 @@ def test_flash_attention_chunked_matches_ref(b, hq, hkv, sq, skv, d,
         q, k, v, causal=causal, window=window, chunk=chunk
     )
     np.testing.assert_allclose(np.asarray(ref), np.asarray(chk),
+                               atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("b,hq,hkv,sq,skv,d,causal,window", FA_CASES + [
+    (1, 4, 2, 64, 1040, 32, True, None),        # Skv past one 1024 chunk
+])
+def test_flash_attention_entry_xla_path_matches_ref(b, hq, hkv, sq, skv, d,
+                                                    causal, window):
+    """The entry point off the Pallas path (every non-TPU platform) vs the
+    oracle, at its default chunk."""
+    rng = np.random.default_rng(b * 7 + skv)
+    q = jnp.asarray(rng.standard_normal((b, hq, sq, d)).astype(np.float32))
+    k = jnp.asarray(rng.standard_normal((b, hkv, skv, d)).astype(np.float32))
+    v = jnp.asarray(rng.standard_normal((b, hkv, skv, d)).astype(np.float32))
+    ref = flash_attention_ref(q, k, v, causal=causal, window=window)
+    out = flash_attention(q, k, v, causal=causal, window=window,
+                          use_pallas=False)
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                atol=3e-5, rtol=3e-5)
 
 

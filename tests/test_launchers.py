@@ -5,8 +5,6 @@ import subprocess
 import sys
 from pathlib import Path
 
-import pytest
-
 ROOT = Path(__file__).resolve().parents[1]
 
 
@@ -54,32 +52,3 @@ def test_serve_launcher_continuous_on_a_mesh():
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "mesh 1x4" in proc.stdout
     assert proc.stdout.count("tok/s") == 2
-
-
-def test_dryrun_launcher_single_cell(tmp_path):
-    out = tmp_path / "cell.jsonl"
-    proc = _run([
-        "repro.launch.dryrun", "--arch", "whisper-small",
-        "--shape", "train_4k", "--out", str(out),
-    ], timeout=560)
-    assert proc.returncode == 0, proc.stderr[-2000:]
-    assert "OK" in proc.stdout
-    import json
-
-    rec = json.loads(out.read_text().splitlines()[0])
-    assert rec["status"] == "ok"
-    assert rec["roofline"]["flops_per_device"] > 0
-    assert rec["mesh"] == "16x16"
-
-
-def test_dryrun_skipped_cell(tmp_path):
-    out = tmp_path / "skip.jsonl"
-    proc = _run([
-        "repro.launch.dryrun", "--arch", "qwen2-72b",
-        "--shape", "long_500k", "--out", str(out),
-    ])
-    assert proc.returncode == 0
-    import json
-
-    rec = json.loads(out.read_text().splitlines()[0])
-    assert rec["status"] == "skipped"

@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_NAMES, get_config
-from repro.configs.base import SHAPES, shape_by_name
 from repro.models.common import unembed_logits
 from repro.models.registry import build_model
 
@@ -166,32 +165,34 @@ def test_full_configs_match_assignment():
     assert get_config("qwen2-72b").qkv_bias
 
 
-def test_full_param_counts_in_published_ballpark():
-    """Abstract init (no allocation) → param totals ≈ the model names."""
-    import sys
-    sys.path.insert(0, "src")
-    from repro.launch.dryrun import abstract_init, param_stats
+PUBLISHED_PARAMS = {
+    "qwen2-72b": (65e9, 85e9),
+    "yi-6b": (5.5e9, 7e9),
+    "gemma3-12b": (10e9, 15e9),
+    "qwen1.5-110b": (100e9, 125e9),
+    "jamba-1.5-large-398b": (350e9, 440e9),
+    "qwen3-moe-235b-a22b": (210e9, 260e9),
+    # the ASSIGNED config (48L × 64e × d_ff 1408) arithmetically implies
+    # ~28B total; the real Moonlight-16B has 27 layers — we implement
+    # the assignment as specified
+    "moonshot-v1-16b-a3b": (24e9, 32e9),
+    "mamba2-1.3b": (1.0e9, 1.7e9),
+    "whisper-small": (0.2e9, 0.5e9),
+    "internvl2-76b": (66e9, 86e9),
+}
 
-    expect = {
-        "qwen2-72b": (65e9, 85e9),
-        "yi-6b": (5.5e9, 7e9),
-        "gemma3-12b": (10e9, 15e9),
-        "qwen1.5-110b": (100e9, 125e9),
-        "jamba-1.5-large-398b": (350e9, 440e9),
-        "qwen3-moe-235b-a22b": (210e9, 260e9),
-        # the ASSIGNED config (48L × 64e × d_ff 1408) arithmetically implies
-        # ~28B total; the real Moonlight-16B has 27 layers — we implement
-        # the assignment as specified
-        "moonshot-v1-16b-a3b": (24e9, 32e9),
-        "mamba2-1.3b": (1.0e9, 1.7e9),
-        "whisper-small": (0.2e9, 0.5e9),
-        "internvl2-76b": (66e9, 86e9),
-    }
-    for arch, (lo, hi) in expect.items():
-        api = build_model(get_config(arch))
-        ps, specs = abstract_init(api)
-        n = param_stats(ps, specs)["total"]
-        assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B outside [{lo/1e9},{hi/1e9}]"
+
+@pytest.mark.parametrize("arch", ARCH_NAMES)
+def test_full_param_counts_in_published_ballpark(arch):
+    """Abstract init (no allocation) → param totals ≈ the model names."""
+    from repro.launch.sharding import abstract
+    from repro.models.common import param_count
+
+    lo, hi = PUBLISHED_PARAMS[arch]
+    api = build_model(get_config(arch))
+    params, _ = abstract(api.init, jax.ShapeDtypeStruct((2,), jnp.uint32))
+    n = param_count(params)
+    assert lo <= n <= hi, f"{arch}: {n/1e9:.2f}B outside [{lo/1e9},{hi/1e9}]"
 
 
 def test_shape_cells_runnable_map():

@@ -9,7 +9,6 @@ verify-mode agreement, and the service's end-to-end async fetch.
 """
 
 import gc
-import json
 import os
 import subprocess
 import sys
@@ -111,7 +110,7 @@ def test_depth_caps_inflight_spans(corpus, targets, hashed_index):
 
 
 # ---------------------------------------------------------------------------
-# env knobs (repro.flags)
+# env knobs (core/iobackend.py, core/reader.py)
 # ---------------------------------------------------------------------------
 
 def test_reader_backend_env_steers_auto(corpus, targets, hashed_index,
@@ -323,34 +322,3 @@ def test_fetch_async_parity_and_read_stats(corpus, targets, hashed_index):
             assert key in s, key
         assert s["backend"] in ("uring", "thread", "mmap", "serial")
         assert s["records"] > 0 and s["verify_records"] > 0
-
-
-# ---------------------------------------------------------------------------
-# scaled benchmark corpus (the --scale knob)
-# ---------------------------------------------------------------------------
-
-@pytest.mark.slow
-def test_bench_scale_flag_multiplies_corpus(tmp_path):
-    """`benchmarks.run --scale N` multiplies records-per-file and the
-    scaled engine bench still reports parity."""
-    extract_json = tmp_path / "BENCH_extract.json"
-    env = dict(os.environ)
-    env.update(
-        PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"),
-        REPRO_BENCH_FILES="2",
-        REPRO_BENCH_RPF="120",
-        REPRO_BENCH_CACHE=str(tmp_path / "bench_cache"),
-        REPRO_BENCH_EXTRACT_OUT=str(extract_json),
-        REPRO_BENCH_SERVICE_OUT=str(tmp_path / "BENCH_service.json"),
-        REPRO_BENCH_SERVICE_SECONDS="0.4",
-    )
-    proc = subprocess.run(
-        [sys.executable, "-m", "benchmarks.run", "--scale", "3"],
-        capture_output=True, text=True, env=env, timeout=560,
-        cwd=Path(__file__).resolve().parents[1],
-    )
-    assert proc.returncode == 0, proc.stdout[-2000:] + proc.stderr[-2000:]
-    m = json.loads(extract_json.read_text())
-    assert m["corpus"]["records_per_file"] == 360  # 120 x 3
-    assert m["parity"] is True
-    assert m["backends"], "per-backend cold rows missing"
